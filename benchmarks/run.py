@@ -65,6 +65,7 @@ from repro.core import (rmat, BipartiteGraph, greedy_color, greedy_color_d2,
                         dataflow_levels, validate_coloring,
                         validate_d2_coloring, validate_pd2_coloring,
                         num_colors, schedule_transfers)
+from repro.compile_cache import enable_compile_cache
 from repro.core.comm_schedule import moe_all_to_all_transfers
 from repro.core.distance2 import wedge_count
 
@@ -589,13 +590,14 @@ def _dist_worker(payload: str) -> None:
                                         partition_graph, slab_entry_bytes)
     from repro.core.frontier import frontier_capacities
     from repro.parallel.compression import halo_bytes, halo_words
-    from repro.jax_compat import set_mesh
+    from jax import set_mesh
 
     cfg = json.loads(payload)
     scale, D = int(cfg["scale"]), int(cfg["devices"])
     assert len(jax.devices()) >= D, "parent must set XLA_FLAGS device count"
     mesh = Mesh(np.asarray(jax.devices()[:D]), ("x",))
-    out = {"devices": D, "graphs": {}}
+    out = {"platform": jax.devices()[0].platform, "devices": D,
+           "graphs": {}}
     for name in GRAPHS:
         g = rmat.paper_graph(name, scale=scale, seed=0)
         V, wc = g.num_vertices, g.max_degree() + 1
@@ -713,6 +715,10 @@ def dist_scale(scale=10, shards=(2, 4, 8), fcv=16):
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count={D}")
+        # the worker meshes are virtual host devices by construction: pin
+        # the CPU so no worker contends for an accelerator this process
+        # (or another) holds
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             [src_dir] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
                          if p])
@@ -725,6 +731,7 @@ def dist_scale(scale=10, shards=(2, 4, 8), fcv=16):
             raise RuntimeError(
                 f"dist worker (D={D}) failed:\n{proc.stderr[-4000:]}")
         res = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert res["platform"] == "cpu", res["platform"]
         for name, per_scheme in res["graphs"].items():
             for scheme, r in per_scheme.items():
                 assert r["rounds"] <= r["rounds_full"] + 1, (name, scheme, D)
@@ -753,7 +760,8 @@ def dist_scale(scale=10, shards=(2, 4, 8), fcv=16):
                      f"Bl={r['boundary_local']}/{r['verts_local']}",
                      us_per_call_full=round(r["us_full"], 1),
                      us_per_round=round(r["us_boundary"] / r["rounds"], 1),
-                     devices=D, scheme=scheme, rounds=r["rounds"],
+                     platform=res["platform"], devices=D, scheme=scheme,
+                     rounds=r["rounds"],
                      rounds_full=r["rounds_full"],
                      conflicts_per_round=r["conf"],
                      frontier_sizes_per_round=r["front"],
@@ -915,4 +923,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -130,7 +130,7 @@ def trace_plan_program(spec, statics):
     sds = jax.ShapeDtypeStruct
 
     if strategy.wants == "host":
-        from ..jax_compat import set_mesh
+        from jax import set_mesh
         # one geometry derivation shared with the SPMD passes' closed-form
         # expectations (spmd.distributed_geometry), so the traced program
         # and the accounting can never disagree about the envelope. The

@@ -41,7 +41,8 @@ from typing import Dict, List, Set, Tuple
 
 from .findings import Finding
 from .jaxpr_walk import Literal, site_of
-from .spmd import (COLLECTIVE_PRIMS, REPLICATING_PRIMS, collective_eqns,
+from .spmd import (COLLECTIVE_PRIMS, REDUCING_PRIMS, REPLICATING_PRIMS,
+                   collective_eqns,
                    collective_signature, cond_branches, find_shard_jaxprs,
                    is_full_axis, mesh_axis_names, sub_jaxpr, while_parts)
 
@@ -231,7 +232,7 @@ def _gather_derived_outputs_with_inputs(jaxpr, in_taint
         if prim == "all_gather":
             tainted.update(eqn.outvars)
             continue
-        if prim in ("psum", "pmin", "pmax"):
+        if prim in REDUCING_PRIMS:
             # a reducing collective CONSUMES the payload: its output is a
             # fresh replicated aggregate (the termination vote), not an
             # exchanged buffer that still needs an in-round reader

@@ -32,7 +32,7 @@ from typing import List, Optional, Set, Tuple
 
 from .findings import Finding
 from .jaxpr_walk import Literal, site_of
-from .spmd import (SpmdGeometry, aval_elems, cond_branches,
+from .spmd import (REDUCING_PRIMS, SpmdGeometry, aval_elems, cond_branches,
                    find_shard_jaxprs, iter_round_loops, sub_jaxpr,
                    while_parts)
 
@@ -72,7 +72,7 @@ def _run(jaxpr, in_taint: List[bool], t: _Taint,
                 (eqn, sum(aval_elems(v) for v in eqn.invars), branch))
             tainted.update(eqn.outvars)
             continue
-        if prim in ("psum", "pmin", "pmax"):
+        if prim in REDUCING_PRIMS:
             continue  # votes: reduced aggregates, not raw payload
         if prim in _SCATTER_PRIMS:
             operand, indices, updates = (eqn.invars + [None, None])[:3]

@@ -77,6 +77,7 @@ def producer_map(jaxpr) -> dict:
 _FILL_PRESERVING = frozenset({
     "broadcast_in_dim", "convert_element_type", "reshape", "copy",
     "squeeze", "expand_dims",
+    "pvary",  # re-tags the varying mesh axes; the values are unchanged
 })
 
 
@@ -103,12 +104,15 @@ def is_constant_fill(var, jaxpr, _prod=None, _depth=0) -> bool:
 def site_of(eqn, fallback: str = "unknown:unknown") -> str:
     """Stable ``file:function`` provenance of an equation."""
     try:
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:
         return fallback
-    return f"{rel_source_path(frame.file_name)}:{frame.function_name}"
+    # the innermost name only: fingerprints stay "file:function" whether
+    # the frame reports a bare or a qualified ("outer.<locals>.f") name
+    func = frame.function_name.rsplit(".", 1)[-1]
+    return f"{rel_source_path(frame.file_name)}:{func}"
 
 
 def rel_source_path(path: str) -> str:

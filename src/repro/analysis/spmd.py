@@ -25,20 +25,22 @@ from typing import Iterator, List, Optional, Tuple
 
 from .jaxpr_walk import walk_eqns
 
-# cross-device communication primitives (jax 0.4.x names). axis_index is
-# shard-VARYING but communicates nothing, so it is a uniformity source for
-# the collective-safety pass, not a collective.
-COLLECTIVE_PRIMS = frozenset({
-    "all_gather", "psum", "pmin", "pmax", "ppermute", "all_to_all",
-    "reduce_scatter", "pgather", "pbroadcast",
+# reductions over named axes. Under varying-axes typing ``lax.psum`` of a
+# device-varying value traces as ``psum_invariant``.
+REDUCING_PRIMS = frozenset({"psum", "psum_invariant", "pmin", "pmax"})
+
+# cross-device communication primitives. axis_index is shard-VARYING but
+# communicates nothing, so it is a uniformity source for the
+# collective-safety pass, not a collective.
+COLLECTIVE_PRIMS = REDUCING_PRIMS | frozenset({
+    "all_gather", "ppermute", "all_to_all", "reduce_scatter", "pgather",
+    "pbroadcast",
 })
 
-# collectives that reduce over the named axes: their output is replicated
-# (identical on every participating device), which is what makes a
-# psum-derived vote a provably shard-uniform predicate.
-REPLICATING_PRIMS = frozenset({
-    "all_gather", "psum", "pmin", "pmax",
-})
+# collectives whose output is replicated (identical on every participating
+# device), which is what makes a psum-derived vote a provably shard-uniform
+# predicate.
+REPLICATING_PRIMS = REDUCING_PRIMS | frozenset({"all_gather"})
 
 
 @dataclasses.dataclass(frozen=True)

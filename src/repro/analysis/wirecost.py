@@ -35,7 +35,7 @@ from typing import Dict, List, Optional
 
 from .findings import Finding
 from .jaxpr_walk import site_of
-from .spmd import (SpmdGeometry, aval_nbytes, cond_branches,
+from .spmd import (REDUCING_PRIMS, SpmdGeometry, aval_nbytes, cond_branches,
                    distributed_geometry, find_shard_jaxprs, iter_round_loops,
                    sub_jaxpr, while_parts)
 
@@ -133,7 +133,7 @@ def _collect(jaxpr, sink, *, in_loop: bool, branch: Optional[int]):
     (1 = predicate-true = the slab wire), else None."""
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
-        if prim in ("all_gather", "psum", "pmin", "pmax"):
+        if prim == "all_gather" or prim in REDUCING_PRIMS:
             sink.append((eqn, in_loop, branch))
         elif prim == "while":
             _, body, _, _ = while_parts(eqn)

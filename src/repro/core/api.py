@@ -476,6 +476,15 @@ class DataflowStrategy(ColoringStrategy):
         return run
 
 
+def _shard_slabs(mesh, *slabs: np.ndarray) -> list:
+    """Upload ``[D, ...]`` per-shard host slabs row-sharded over every
+    mesh axis, so each device receives only its own row (the layout the
+    shard_map program's in_specs expect — no reshard inside the jit)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sharding = NamedSharding(mesh, P(tuple(mesh.axis_names), None))
+    return [jax.device_put(s, sharding) for s in slabs]
+
+
 @dataclasses.dataclass(frozen=True)
 class DistributedStrategy(ColoringStrategy):
     """The Bozdag-style BSP driver over a jax mesh — distributed.py. A host
@@ -525,7 +534,7 @@ class DistributedStrategy(ColoringStrategy):
                            np.bool_(unconverged), np.asarray(fronts))
 
     def oneshot(self, spec: ColoringSpec, g) -> RawColoring:
-        from ..jax_compat import set_mesh
+        from jax import set_mesh
         from .distributed import partition_graph
         host = constraint_host_graph(g, spec.model, side=spec.side)
         mesh = self._mesh(spec)
@@ -542,15 +551,14 @@ class DistributedStrategy(ColoringStrategy):
                          wire=wire, wire_colors=host.max_degree() + 1)
         with set_mesh(mesh):
             colors, rounds, conf, sweeps, fronts = fn(
-                jnp.asarray(layout.lsrc), jnp.asarray(layout.ldst),
-                jnp.asarray(layout.bnd))
+                *_shard_slabs(mesh, layout.lsrc, layout.ldst, layout.bnd))
         colors = layout.unpermute(np.asarray(colors).reshape(-1))
         return self._raw(spec, host.num_vertices, colors, rounds, conf,
                          sweeps, fronts)
 
     def compile(self, spec: ColoringSpec, statics: "PlanShape",
                 trace_hook: Callable[[], None]) -> Callable:
-        from ..jax_compat import set_mesh
+        from jax import set_mesh
         from .distributed import partition_graph
         mesh = self._mesh(spec)
         D = int(np.prod(mesh.devices.shape))
@@ -617,8 +625,7 @@ class DistributedStrategy(ColoringStrategy):
                 bnd = np.full((D, 1), layout.verts_local, np.int32)
             with set_mesh(mesh):
                 colors, rounds, conf, sweeps, fronts = run(
-                    jnp.asarray(layout.lsrc), jnp.asarray(layout.ldst),
-                    jnp.asarray(bnd))
+                    *_shard_slabs(mesh, layout.lsrc, layout.ldst, bnd))
             colors = layout.unpermute(np.asarray(colors).reshape(-1))
             return self._raw(spec, statics.num_vertices, colors, rounds,
                              conf, sweeps, fronts)
@@ -773,12 +780,14 @@ class ColoringPlan:
 
     ``plan.traces`` counts jit traces of the underlying program(s) — it
     stays at 1 (2 once ``map`` is also used) however many same-bucket
-    graphs are served; the test suite pins this.
+    graphs are served; the test suite pins this. ``plan.backend`` is the
+    resolved mex backend the program binds (for the Pallas engines, its
+    ``interpret`` says whether the kernel runs compiled or interpreted).
     """
 
     def __init__(self, spec: ColoringSpec, graph_or_shape):
         self.spec = spec
-        self.strategy, self._backend = spec.resolve()
+        self.strategy, self.backend = spec.resolve()
         self.statics = _plan_shape(spec, graph_or_shape)
         if spec.ordering not in ORDERINGS:
             raise ValueError(f"unknown ordering {spec.ordering!r}; "
@@ -833,7 +842,7 @@ class ColoringPlan:
                 "PlanShape.max_degree (the color tables would drop forbids)")
         if self.strategy.wants == "host":
             return host, perm
-        layout = ("edges", "ell") if self._backend.needs_ell else "edges"
+        layout = ("edges", "ell") if self.backend.needs_ell else "edges"
         dg = host.to_device(layout=layout, pad_edges_to=st.padded_edges,
                             ell_width=max(1, st.max_degree))
         # pin the static metadata to the envelope: num_directed_edges and
@@ -864,6 +873,20 @@ class ColoringPlan:
             return None
         from ..analysis.wirecost import wire_cost_table
         return wire_cost_table(self.spec, self.statics)
+
+    def lower(self, g, **runtime):
+        """The plan's jitted program lowered for graph ``g`` (plus any
+        per-call state), as a ``jax.stages.Lowered``: ``.compile()
+        .as_text()`` is the HLO the device runs — e.g. whether a Pallas
+        engine's kernel compiled to a ``tpu_custom_call``. Device
+        strategies only (host strategies run several programs)."""
+        if self.strategy.wants == "host" or self._degenerate:
+            raise NotImplementedError(
+                f"plan.lower needs a compiled device program; strategy "
+                f"{self.strategy.name!r} with this envelope has none")
+        canon, _ = self._canonicalize(g)
+        state = self.strategy.plan_state(self.spec, self.statics, **runtime)
+        return self._executor.lower(canon, *state)
 
     # ------------------------------------------------------------ execution
     def __call__(self, g, **runtime) -> ColoringReport:

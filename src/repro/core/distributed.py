@@ -55,7 +55,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jax_compat import pvary, shard_map
 from ..parallel.compression import halo_words, pack_halo, unpack_halo
 
 from .engine import (EngineSpec, SweepSpec, edge_slots, fixpoint_sweep,
@@ -273,9 +272,9 @@ def _bsp_local(lsrc, ldst, bnd, *, axis_names: Tuple[str, ...],
         return lax.all_gather(x, axis_names, tiled=True)
 
     def pv(x):
-        # mark as device-varying so while_loop carries type-check under
-        # shard_map's varying-manual-axes tracking
-        return pvary(x, axis_names)
+        # mark as device-varying so while_loop carries and cond branches
+        # type-check under shard_map's varying-manual-axes tracking
+        return lax.pcast(x, axis_names, to="varying")
 
     if use_boundary and Bl > 0:
         Wb = halo_words(Bl, wire_colors)
@@ -324,13 +323,15 @@ def _bsp_local(lsrc, ldst, bnd, *, axis_names: Tuple[str, ...],
                                         dst_pad=Vp)
 
             def _empty_slab(_):
+                # constant fills are device-invariant; the compacting
+                # branch's slabs vary per device, so both must be varying
                 return FrontierSlab(
-                    vert=jnp.full((frontier_cap_v,), Vl, jnp.int32),
-                    owner=jnp.full((frontier_cap_e,), frontier_cap_v,
-                                   jnp.int32),
-                    src=jnp.full((frontier_cap_e,), Vl, jnp.int32),
-                    dst=jnp.full((frontier_cap_e,), Vp, jnp.int32),
-                    slot=jnp.zeros((frontier_cap_e,), jnp.int32),
+                    vert=pv(jnp.full((frontier_cap_v,), Vl, jnp.int32)),
+                    owner=pv(jnp.full((frontier_cap_e,), frontier_cap_v,
+                                      jnp.int32)),
+                    src=pv(jnp.full((frontier_cap_e,), Vl, jnp.int32)),
+                    dst=pv(jnp.full((frontier_cap_e,), Vp, jnp.int32)),
+                    slot=pv(jnp.zeros((frontier_cap_e,), jnp.int32)),
                     nv=nv, ne=ne)
 
             slab = lax.cond(fits_solve | all_fit, _compact, _empty_slab, 0)
@@ -549,11 +550,16 @@ def build_distributed_coloring(mesh: Mesh, verts_local: int, edges_local: int,
         frontier_cap_v=frontier_cap_v, frontier_cap_e=frontier_cap_e,
         wire=wire, wire_colors=wire_colors)
     spec_in = P(axis_names, None)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_in, spec_in, spec_in),
         out_specs=(P(axis_names, None), P(axis_names), P(axis_names, None),
                    P(axis_names, None), P(axis_names, None)),
+        # the Pallas interpreter (the CPU path of the kernel engines)
+        # evaluates kernel bodies with untyped constants, which varying-
+        # axes checking rejects against device-varying blocks; compiled
+        # kernels and every other engine keep the check
+        check_vma=not getattr(backend, "interpret", False),
     )
 
     def run(lsrc, ldst, bnd):

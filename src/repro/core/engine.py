@@ -170,6 +170,17 @@ class MexBackend:
                         max_degree=max_degree)
         return lambda key_v, key_c, slot: mex(key_v, key_c)
 
+    def resolved(self) -> "MexBackend":
+        """This backend with its call-time defaults decided — a Pallas
+        backend's ``interpret=None`` becomes the current backend's mode
+        (:func:`repro.kernels.ops.resolve_interpret`). :func:`get_backend`
+        returns resolved instances, so the backend a jit sees as a static
+        argument never carries an undecided ``None``."""
+        if getattr(self, "interpret", False) is None:
+            from ..kernels.ops import resolve_interpret
+            return dataclasses.replace(self, interpret=resolve_interpret())
+        return self
+
 
 @dataclasses.dataclass(frozen=True)
 class SortMexBackend(MexBackend):
@@ -245,7 +256,7 @@ class EllPallasMexBackend(MexBackend):
 
     def bind(self, *, num_vertices: int, max_colors: int = 0,
              ell_slot=None, ell_width: int = 0, max_degree: int = -1) -> MexFn:
-        from ..kernels import ops as kernel_ops  # deferred: keeps core importable solo
+        from ..kernels.ops import resolve_interpret  # deferred: core importable solo
 
         if ell_slot is None:
             raise ValueError(
@@ -265,7 +276,7 @@ class EllPallasMexBackend(MexBackend):
         V = num_vertices
         D = max(1, int(ell_width))
         words = _resolve_words(self.words, max_colors, self.name)
-        interp = kernel_ops.INTERPRET if self.interpret is None else self.interpret
+        interp = resolve_interpret(self.interpret)
         from ..kernels.firstfit import firstfit
 
         def mex(key_v, key_c):
@@ -280,8 +291,8 @@ class EllPallasMexBackend(MexBackend):
         """Frontier bind: the kernel consumes a compacted (capacity, D) ELL
         slab scattered through the per-round ``slot`` operand — no static
         ell_slot needed, the compaction computes row positions itself."""
-        from ..kernels import ops as kernel_ops
         from ..kernels.firstfit import firstfit
+        from ..kernels.ops import resolve_interpret
 
         D = max(1, int(ell_width if ell_width > 0 else max_degree))
         if max_degree > D:
@@ -289,7 +300,7 @@ class EllPallasMexBackend(MexBackend):
                 f"ell_pallas slab bind: width {D} is below the graph's max "
                 f"degree {max_degree}; a frontier row would drop forbids")
         words = _resolve_words(self.words, max_colors, self.name)
-        interp = kernel_ops.INTERPRET if self.interpret is None else self.interpret
+        interp = resolve_interpret(self.interpret)
         cap = int(capacity)
 
         def mex(key_v, key_c, slot):
@@ -398,11 +409,12 @@ def register_backend(backend: MexBackend, *, overwrite: bool = False) -> MexBack
 
 
 def get_backend(engine: EngineSpec) -> MexBackend:
-    """Resolve ``engine=`` — a registered name or a MexBackend instance."""
+    """Resolve ``engine=`` — a registered name or a MexBackend instance —
+    with its call-time defaults decided (:meth:`MexBackend.resolved`)."""
     if isinstance(engine, MexBackend):
-        return engine
+        return engine.resolved()
     try:
-        return _REGISTRY[engine]
+        return _REGISTRY[engine].resolved()
     except KeyError:
         raise ValueError(
             f"unknown mex backend {engine!r}; registered: "

@@ -181,7 +181,10 @@ def compact_frontier(active: jnp.ndarray, inc_ptr: jnp.ndarray,
     slot = eidx - starts[owner]
     src = vert[owner]                            # [cap_e], V on empty rows
     gidx = inc_ptr[jnp.minimum(src, V)] + slot   # src <= V indexes [V+1] ptr
-    gdst = dst[jnp.clip(gidx, 0, dst.shape[0] - 1)]
+    if dst.shape[0]:
+        gdst = dst[jnp.clip(gidx, 0, dst.shape[0] - 1)]
+    else:  # no edges: every slab edge is padding, and there is no
+        gdst = jnp.full((cap_e,), fill, jnp.int32)  # element to gather
     return FrontierSlab(
         vert=vert,
         owner=jnp.where(valid, owner, cap_v),

@@ -13,20 +13,20 @@ The kernels reach the coloring drivers exclusively through the
 under the distributed driver) and scatters each round's ``SweepSpec``
 contributions into the [V, D] slab the kernel consumes. Drivers never
 hand-wire kernel closures; registering a different kernel is a new
-``MexBackend`` subclass (DESIGN.md §Engine). Off-TPU the kernels run in
-Pallas interpret mode (``ops.INTERPRET``).
+``MexBackend`` subclass (DESIGN.md §Engine). On the CPU the kernels run in
+Pallas interpret mode (``ops.resolve_interpret``, decided per call).
 """
 from .firstfit import firstfit
 from .conflict import conflict_mask
 from .ref import firstfit_ref, conflict_mask_ref
-from .ops import ell_mex, ell_gather_colors, INTERPRET, resolve_interpret
+from .ops import ell_mex, ell_gather_colors, resolve_interpret
 from .round_fused import (round_fused, round_fused_ref, pack_entries,
                           tile_conflict_counts, COLOR_MASK, FORBID_BIT,
                           CONFLICT_BIT)
 
 __all__ = [
     "firstfit", "conflict_mask", "firstfit_ref", "conflict_mask_ref",
-    "ell_mex", "ell_gather_colors", "INTERPRET",
-    "resolve_interpret", "round_fused", "round_fused_ref", "pack_entries",
-    "tile_conflict_counts", "COLOR_MASK", "FORBID_BIT", "CONFLICT_BIT",
+    "ell_mex", "ell_gather_colors", "resolve_interpret", "round_fused",
+    "round_fused_ref", "pack_entries", "tile_conflict_counts", "COLOR_MASK",
+    "FORBID_BIT", "CONFLICT_BIT",
 ]

@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .tpu_compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _conflict_kernel(csrc_ref, cdst_ref, src_ref, dst_ref, out_ref):
@@ -60,7 +59,7 @@ def conflict_mask(
         in_specs=[spec] * 4,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((ep,), jnp.int32),
-        compiler_params=TPUCompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
     return out[:e]

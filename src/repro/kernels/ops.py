@@ -1,8 +1,9 @@
 """Jit'd wrappers wiring the Pallas kernels into the coloring engine.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python for correctness); on TPU the same calls
-compile to Mosaic. ``INTERPRET`` flips automatically based on the backend.
+On a TPU the kernels compile to Mosaic; on the CPU (the test path) they run
+with ``interpret=True`` — Pallas executes the kernel body in its
+interpreter. :func:`resolve_interpret` picks between the two when a call
+leaves ``interpret`` unset, from the backend in use at call time.
 
 The speculative drivers no longer thread kernel closures through here: the
 ``"ell_pallas"`` entry in :mod:`repro.core.engine` binds the firstfit kernel
@@ -18,20 +19,20 @@ import jax
 import jax.numpy as jnp
 
 from .firstfit import firstfit
-from .conflict import conflict_mask
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
-    """Resolve an ``interpret=`` override against the module-level
-    ``INTERPRET`` default.
+    """Resolve an ``interpret=`` override against the default backend as
+    it is at call time: compiled (``False``) on a TPU, the Pallas
+    interpreter (``True``) on the CPU. Any other backend has no default —
+    the kernels are Mosaic TPU kernels — and raises rather than silently
+    interpreting; pass ``interpret=True`` explicitly there.
 
     Every kernel entry point that accepts ``interpret=None`` must call this
     OUTSIDE its jit boundary: ``interpret`` is a static argument, so a
     fallback read inside a jitted body is frozen at first trace — the cache
-    key stays ``None`` and a later flip of ``ops.INTERPRET`` (tests, TPU
-    attach) silently keeps serving the stale trace.
+    key stays ``None`` and a later change of backend silently keeps serving
+    the stale trace.
 
     This is exactly the hazard class the static analyzer lints for as
     RETRACE001 (:mod:`repro.analysis.retrace`): a static jit arg that
@@ -39,7 +40,17 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     The CI lint lane keeps the package free of new instances; this function
     is the sanctioned fix pattern.
     """
-    return INTERPRET if interpret is None else bool(interpret)
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels are TPU (Mosaic) kernels and have no compiled "
+        f"path on backend {platform!r}; pass interpret=True to run them in "
+        "the Pallas interpreter")
 
 
 def ell_gather_colors(colors: jnp.ndarray, ell: jnp.ndarray) -> jnp.ndarray:
@@ -65,5 +76,3 @@ def ell_mex(colors: jnp.ndarray, ell: jnp.ndarray, *, words: int = 16,
     """mex per vertex from an ELL slab — kernel-powered Alg. 1 inner loop."""
     return _ell_mex(colors, ell, words=words,
                     interpret=resolve_interpret(interpret))
-
-

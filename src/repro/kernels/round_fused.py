@@ -18,8 +18,9 @@ detect-and-recolor (arXiv:1505.04086): per vertex tile of the (compacted)
 ELL slab it
 
   * builds the per-row forbidden-color **bitset** in VMEM scratch (the
-    ``firstfit.py`` word-mask idiom — ``W = C/32`` uint32 words,
-    accumulated across neighbor-slot tiles);
+    ``firstfit.py`` word-mask idiom — ``W = C/32`` int32 words,
+    accumulated across neighbor-slot tiles, slot-major so vertices sit
+    on the lanes);
   * applies the Alg. 2 conflict predicate against the row's own color in
     the same slab read;
   * emits the mex (the row's next color) and the per-row conflict flag on
@@ -53,7 +54,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .tpu_compat import TPUCompilerParams
+from .firstfit import (accumulate_bitset, bitset_mex, init_bitset, or_rows,
+                       or_sublanes, row_shape, slot_block,
+                       to_slot_major)
 
 # packed-entry layout: bits 0..27 color, bit 28 forbid, bit 29 conflict
 COLOR_MASK = (1 << 28) - 1
@@ -74,74 +77,51 @@ def pack_entries(colors: jnp.ndarray, forbid: jnp.ndarray,
 
 def _round_fused_kernel(ent_ref, own_ref, mex_ref, conf_ref, forb_ref,
                         hit_ref, *, words: int):
-    """One (vertex-tile, slot-tile) grid step.
+    """One (vertex-tile, slot-tile) grid step, slot-major like ``firstfit``.
 
-    ent_ref:  [BV, BD] int32 packed entries (color | FORBID? | CONFLICT?)
-    own_ref:  [BV]     int32 the row's current color (conflict operand)
-    mex_ref:  [BV]     int32 mex output (written on the last slot tile)
-    conf_ref: [BV]     int32 conflict flag output (last slot tile)
-    forb_ref: [BV, W]  uint32 VMEM scratch, persists across slot tiles
-    hit_ref:  [BV]     int32 VMEM scratch: conflict accumulator
+    ent_ref:  [BD, BV]    int32 packed entries (color | FORBID? | CONFLICT?)
+    own_ref:  [1, BV]     int32 the row's current color (conflict operand)
+    mex_ref:  [1, BV]     int32 mex output (written on the last slot tile)
+    conf_ref: [1, BV]     int32 conflict flag output (last slot tile)
+    forb_ref: [W, 8, BV]  int32 VMEM scratch, persists across slot tiles
+    hit_ref:  [8, BV]     int32 VMEM scratch: partial conflict flags
     """
     j = pl.program_id(1)
-    nd = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        # color 0 ("uncolored") is always forbidden: bit 0 of word 0
-        init = jnp.zeros(forb_ref.shape, jnp.uint32)
-        forb_ref[...] = init.at[:, 0].set(jnp.uint32(1))
+        init_bitset(forb_ref)
         hit_ref[...] = jnp.zeros(hit_ref.shape, jnp.int32)
 
-    ent = ent_ref[...]                                     # [BV, BD] int32
+    ent = ent_ref[...]                                     # [BD, BV] int32
     color = ent & COLOR_MASK
-    forbid = (ent & FORBID_BIT) != 0
-    elig = (ent & CONFLICT_BIT) != 0
 
     # --- detect: Alg. 2 line 13 against the row's own color -------------
-    own = own_ref[...]                                     # [BV]
-    hit = elig & (color == own[:, None]) & (own[:, None] > 0)
-    hit_ref[...] = hit_ref[...] | hit.any(axis=1).astype(jnp.int32)
+    own = own_ref[...]                                     # [1, BV]
+    hit = ((ent & CONFLICT_BIT) != 0) & (color == own) & (own > 0)
+    hit_ref[...] = hit_ref[...] | or_rows(hit.astype(jnp.int32))
 
     # --- mex part 1: accumulate the forbidden bitset (firstfit idiom) ---
-    word_idx = (color >> 5).astype(jnp.int32)              # [BV, BD]
-    bit = (color & 31).astype(jnp.uint32)
-    bitval = jnp.where(forbid, jnp.uint32(1) << bit, jnp.uint32(0))
-    contrib = jnp.where(
-        word_idx[:, :, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, words), 2),
-        bitval[:, :, None],
-        jnp.uint32(0),
-    )                                                      # [BV, BD, W]
-    orred = jax.lax.reduce(contrib, jnp.uint32(0), jax.lax.bitwise_or, (1,))
-    forb_ref[...] = forb_ref[...] | orred
+    accumulate_bitset(forb_ref, color, (ent & FORBID_BIT) != 0, words=words)
 
-    @pl.when(j == nd - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        # mex part 2: expand words to bit lanes, min-reduce free candidates
-        forb = forb_ref[...]                               # [BV, W]
-        lanes = jax.lax.broadcasted_iota(jnp.uint32, (1, words, 32), 2)
-        bits = (forb[:, :, None] >> lanes) & jnp.uint32(1)  # [BV, W, 32]
-        value = (
-            jax.lax.broadcasted_iota(jnp.int32, (1, words, 32), 1) * 32
-            + jax.lax.broadcasted_iota(jnp.int32, (1, words, 32), 2)
-        )
-        cand = jnp.where(bits == 0, value, jnp.iinfo(jnp.int32).max)
-        mex_ref[...] = jnp.min(cand.reshape(cand.shape[0], -1), axis=1)
-        conf_ref[...] = hit_ref[...]
+        # mex part 2: lowest free bit across the words; conflict flag
+        mex_ref[...] = bitset_mex(forb_ref, words=words)[0:1]
+        conf_ref[...] = or_sublanes(hit_ref[...])[0:1]
 
 
 def vmem_estimate(*, words: int = 16, block_v: int = 512,
                   block_d: int = 128) -> int:
     """Per-grid-step VMEM footprint (bytes) of :func:`round_fused`'s launch
     geometry, for the analyzer's budget checker (repro.analysis.budgets):
-    the packed-entry + own-color input blocks, the mex/conflict output
-    blocks, the ``[BV, W]`` bitset and ``[BV]`` hit scratch, and the larger
-    of the ``[BV, BD, W]`` contribution tensor and the ``[BV, W, 32]``
-    bit-lane expansion (same idiom as ``firstfit.vmem_estimate``)."""
+    the [BD, BV] packed-entry and [1, BV] own-color input blocks, the two
+    [1, BV] output blocks, the ``[W, 8, BV]`` bitset and ``[8, BV]`` hit
+    scratch, and the [BD, BV] per-word contribution intermediate (same
+    geometry as ``firstfit.vmem_estimate``)."""
     blocks = 4 * block_v * (block_d + 3)
-    scratch = 4 * block_v * (words + 1)
-    intermediate = 4 * block_v * words * max(block_d, 32)
+    scratch = 4 * (words + 1) * 8 * block_v
+    intermediate = 4 * block_d * block_v
     return blocks + scratch + intermediate
 
 
@@ -173,36 +153,28 @@ def round_fused(
     padded internally to the block shape (pad entries are inert).
     """
     v, d = entries.shape
+    bd = slot_block(d, block_d)
     vp = -(-v // block_v) * block_v
-    dp = -(-d // block_d) * block_d
-    x = jnp.zeros((vp, dp), jnp.int32).at[:v, :d].set(entries)
-    own = jnp.zeros((vp,), jnp.int32).at[:v].set(own_colors)
-    grid = (vp // block_v, dp // block_d)
+    dp = -(-d // bd) * bd
+    x = to_slot_major(entries, vp, dp)
+    own = jnp.zeros((1, vp), jnp.int32).at[0, :v].set(own_colors)
+    row = pl.BlockSpec((1, block_v), lambda i, j: (0, i))
     mex, conf = pl.pallas_call(
         functools.partial(_round_fused_kernel, words=words),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_v, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_v,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_v,), lambda i, j: (i,)),
-            pl.BlockSpec((block_v,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((vp,), jnp.int32),
-            jax.ShapeDtypeStruct((vp,), jnp.int32),
-        ],
+        grid=(vp // block_v, dp // bd),
+        in_specs=[pl.BlockSpec((bd, block_v), lambda i, j: (j, i)), row],
+        out_specs=[row, row],
+        out_shape=[row_shape(x, vp)] * 2,
         scratch_shapes=[
-            pltpu.VMEM((block_v, words), jnp.uint32),
-            pltpu.VMEM((block_v,), jnp.int32),
+            pltpu.VMEM((words, 8, block_v), jnp.int32),
+            pltpu.VMEM((8, block_v), jnp.int32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(x, own)
-    return mex[:v], conf[:v]
+    return mex[0, :v], conf[0, :v]
 
 
 def tile_conflict_counts(conflict: jnp.ndarray,

@@ -1,11 +1,15 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 virtual host devices: a CPU-only program by construction, so pin the
+# platform too (an attached accelerator stays free for other processes)
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell on the production meshes and extract the roofline terms.
 
-MUST be run as a script/module (the XLA_FLAGS line above precedes every jax
-import):  PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b \
+MUST be run as a script/module (the XLA_FLAGS/JAX_PLATFORMS lines above
+precede every jax import):  PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b \
              --shape train_4k [--multi-pod] [--out results/dryrun]
 
 Per cell this emits a JSON record with:
@@ -29,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.jax_compat import set_mesh
+from jax import set_mesh
 from repro.models import counting
 from repro.models.config import SHAPES
 from repro import models

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import get_config, get_smoke_config
-from ..jax_compat import set_mesh
+from jax import set_mesh
 from .. import models
 from ..train import (AdamWConfig, init_opt_state, make_train_step, checkpoint,
                      data)

@@ -194,11 +194,12 @@ class TestBudgets:
         assert codes(got) == ["IDX002"]
 
     def test_high_degree_breaches_declared_vmem(self):
-        # max_degree 4096 -> 129 forbidden-bitset words -> the fused
-        # kernel's own closed-form model lands ~34 MB, over the 16 MiB
-        # default ceiling, with no tracing involved
+        # max_degree 32768 -> 1025 forbidden-bitset words -> the fused
+        # kernel's own closed-form model (a [W, 8, 512] int32 bitset) lands
+        # ~17 MB, over the 16 MiB default ceiling, with no tracing involved
         got = budgets.check_spec_budgets(
-            ColoringSpec(engine="fused_pallas"), PlanShape(512, 4096, 4096))
+            ColoringSpec(engine="fused_pallas"),
+            PlanShape(512, 1 << 16, 1 << 15))
         assert codes(got) == ["VMEM001"]
         assert got[0].site == "kernels/round_fused.py:round_fused"
 

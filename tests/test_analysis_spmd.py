@@ -19,7 +19,7 @@ from repro.analysis import (AnalysisError, SpmdGeometry, analyze_spec,
                             check_collectives, check_halo_exactness,
                             check_wire_cost, verify_plan)
 from repro.core.api import ColoringSpec, PlanShape, compile_plan
-from repro.jax_compat import shard_map
+from jax import shard_map
 
 sds = jax.ShapeDtypeStruct
 SHAPE = PlanShape(num_vertices=48, padded_edges=512, max_degree=8)
@@ -50,6 +50,13 @@ def toy_geometry(**kw):
 X8 = sds((8,), jnp.int32)
 
 
+def _varying(v):
+    """Tag ``v`` device-varying over "x": a psum result (or a constant)
+    must carry the same varying-axes type as the branch or loop value it
+    meets."""
+    return lax.pcast(v, "x", to="varying")
+
+
 # --------------------------------------------------------------------------
 # collective safety: one toy program per COLL code
 # --------------------------------------------------------------------------
@@ -57,7 +64,7 @@ class TestCollectives:
     def test_branch_mismatch_under_varying_pred_is_coll201(self):
         def fn(x):
             return lax.cond(x[0] > 0,                 # shard-varying
-                            lambda v: lax.psum(v, "x"),
+                            lambda v: _varying(lax.psum(v, "x")),
                             lambda v: v * 2, x)
         got = codes(check_collectives(mesh_jaxpr(fn, X8)))
         assert "COLL201" in got and "COLL103" not in got
@@ -74,7 +81,7 @@ class TestCollectives:
         def fn(x):
             total = lax.psum(x.sum(), "x")            # replicated vote
             return lax.cond(total > 0,
-                            lambda v: lax.psum(v, "x"),
+                            lambda v: _varying(lax.psum(v, "x")),
                             lambda v: v * 2, x)
         got = codes(check_collectives(mesh_jaxpr(fn, X8)))
         assert "COLL102" in got
@@ -120,7 +127,7 @@ class TestCollectives:
                 s2 = jnp.concatenate([g, s[2:]])
                 return s2, i + s2[0] * 0                    # read in-round
             s, _ = lax.while_loop(lambda c: c[1] < 3, body,
-                                  (x, jnp.int32(0)))
+                                  (x, _varying(jnp.int32(0))))
             return s
         assert "COLL203" not in codes(check_collectives(mesh_jaxpr(fn, X8)))
 

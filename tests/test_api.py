@@ -354,3 +354,38 @@ def test_plan_shape_rejections():
         compile_plan(spec, ring.to_device())
     with pytest.raises(ValueError, match="relabels on host"):
         color(ring.to_device(), ordering="random")
+
+
+# ------------------------------------------------- introspection and cache
+def test_plan_lower_exposes_the_compiled_program():
+    """``plan.lower(g)`` is the exact program ``plan(g)`` runs: compiling
+    it serves the call (no second trace), and its HLO is inspectable —
+    on a TPU that is where a Pallas engine's ``tpu_custom_call`` shows."""
+    g = _graph(scale=7)
+    plan = compile_plan(ColoringSpec(engine="fused_pallas"), g)
+    assert plan.backend.interpret is True            # the CPU test path
+    text = plan.lower(g).compile().as_text()
+    assert "while" in text
+    assert validate_coloring(g, plan(g).colors)
+    assert plan.traces == 1
+    dist = compile_plan(ColoringSpec(strategy="distributed"), g)
+    with pytest.raises(NotImplementedError, match="plan.lower"):
+        dist.lower(g)
+
+
+def test_compile_cache_rule(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory
+    (nothing is set in code); otherwise the fixed in-checkout path."""
+    from repro import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    assert compile_cache.enable_compile_cache() == "/cache/from/env"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(compile_cache.CHECKOUT_CACHE)
+    assert path.endswith(".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert compile_cache.enable_compile_cache() == path   # fixed, not fresh

@@ -158,7 +158,7 @@ def test_sharded_train_step_2x2():
         def fn(p, o, b):
             with activation_rules(rules):
                 return step(p, o, b)
-        from repro.jax_compat import set_mesh
+        from jax import set_mesh
         with set_mesh(mesh):
             p2, o2, m = jax.jit(fn, in_shardings=(p_sh, None, None))(params_dev, opt, batch)
         diff = max(float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
@@ -174,7 +174,7 @@ def test_compressed_psum_multidevice():
     res = _run_subprocess(textwrap.dedent("""
         import json, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.jax_compat import shard_map
+        from jax import shard_map
         from repro.parallel.compression import compressed_psum
         mesh = jax.make_mesh((4,), ("d",))
         x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64)), jnp.float32)

@@ -164,6 +164,19 @@ def test_compaction_explicit_cases():
             _check_compaction(g, mask)
 
 
+@pytest.mark.parametrize("n", [1, 2, 9])
+def test_compaction_of_edgeless_graph(n):
+    """E=0: the slab holds the active vertices and only padding edges —
+    compaction must not gather from the empty edge-target array."""
+    g = Graph.from_edges(n, np.zeros((0, 2), np.int64))
+    assert g.num_directed_edges == 0
+    for mask in (np.zeros(n, bool), np.ones(n, bool)):
+        _check_compaction(g, mask)
+        slab = compact_frontier(np.asarray(mask), g.to_device().inc_ptr,
+                                g.to_device().dst, 8, 8)
+        assert (np.asarray(slab.dst) == n).all()
+
+
 def test_compaction_overflow_reports_true_counts():
     """When the active set exceeds the slab, nv/ne still report the TRUE
     counts (the spill signal) and the slab stays well-formed."""

@@ -81,3 +81,21 @@ def test_iterative_with_kernel_mex_engine():
     assert res_k.rounds == res_s.rounds
     np.testing.assert_array_equal(np.asarray(res_k.conflicts_per_round),
                                   np.asarray(res_s.conflicts_per_round))
+
+
+def test_importing_repro_initialises_no_backend():
+    """Importing the package decides nothing about the device: no backend
+    is initialised until a call needs one (the kernels' interpret mode is
+    resolved per call, not at import)."""
+    import os
+    import subprocess
+    import sys
+    code = ("import repro, repro.core, repro.kernels, repro.serve, "
+            "repro.analysis\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -110,15 +110,25 @@ def test_tile_conflict_counts():
 
 # ------------------------------------------------- interpret default pin
 def test_resolve_interpret_follows_module_default(monkeypatch):
-    """Regression pin (ISSUE 6 satellite): ``interpret=None`` must resolve
-    against the CURRENT ``ops.INTERPRET`` at call time — never bake the
-    trace-time value into a jit cache keyed on ``None``."""
+    """Regression pin: ``interpret=None`` resolves against the backend in
+    use at call time — compiled on a TPU, interpreted on the CPU, an error
+    elsewhere (no silent fallback) — and the engine registry hands out
+    backends with the decision already made, so no jit cache is keyed on
+    ``None``."""
+    import jax
     from repro.kernels import ops
-    assert ops.resolve_interpret(None) == ops.INTERPRET
     assert ops.resolve_interpret(True) is True
     assert ops.resolve_interpret(False) is False
-    monkeypatch.setattr(ops, "INTERPRET", not ops.INTERPRET)
-    assert ops.resolve_interpret(None) == ops.INTERPRET
+    assert ops.resolve_interpret(None) is True          # the CPU test path
+    assert get_backend("fused_pallas").interpret is True
+    assert get_backend("sort") is get_backend("sort")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False
+    assert get_backend("ell_pallas").interpret is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ops.resolve_interpret(None)
+    assert ops.resolve_interpret(True) is True
 
 
 # ------------------------------------------------------ the parity matrix

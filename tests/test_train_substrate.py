@@ -160,7 +160,7 @@ def test_prefetch_waves_conflict_free():
 
 # ------------------------------------------------------------ compression
 def test_compressed_psum_close_to_exact():
-    from repro.jax_compat import shard_map
+    from jax import shard_map
     from repro.parallel.compression import compressed_psum
     import jax
     # single-device psum via shard_map over a trivial mesh
