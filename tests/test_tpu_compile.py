@@ -116,7 +116,6 @@ def test_iterative_sort_plan_compiles_at_scale20(one_chip):
     text = compiled.as_text()
     assert "sort" in text and "while" in text
     assert _device_bytes(compiled) < HBM_BYTES
-    assert np.all([s.sharding == one_chip for s in (dg.src, dg.dst)])
 
 
 @pytest.mark.parametrize("devices", [1, 4])
