@@ -55,6 +55,7 @@ from .engine import EngineSpec, MexBackend, get_backend
 from .frontier import FRONTIER_MODES, frontier_capacities, resolve_frontier
 from .graph import BipartiteGraph, DeviceGraph, Graph, pad_bucket
 from .ordering import ORDERINGS
+from . import spans
 
 _LOWERINGS = ("auto", "wedge", "square")
 WIRE_MODES = ("auto", "boundary", "full")
@@ -266,7 +267,9 @@ class ColoringReport:
     vertices round r swept through the compacted frontier slab (0 = the
     round took the full-edge path; DATAFLOW reports its slab-compacted
     sweep count in entry 0). ``wall_time_s`` covers lowering + execution +
-    host transfer (plan-batched runs report the amortized per-graph time).
+    host transfer: for ``plan(g)`` it is the ``plan.call`` span's duration
+    (:mod:`repro.core.spans`); plan-batched runs report the amortized
+    per-graph time.
 
     Summary scalars (``num_colors``, ``total_conflicts``, ``sweeps``) are
     memoized — reports get re-summarized in benchmark/serving loops, and a
@@ -347,11 +350,12 @@ class ColoringStrategy:
     def plan_state(self, spec: ColoringSpec, statics: "PlanShape",
                    **runtime) -> Tuple:
         """Normalize per-call runtime state (``plan(g, key=value, ...)``)
-        into the extra device arguments this strategy's compiled program
-        takes. The base strategies are stateless — any runtime kwarg is an
-        error; the ``"recolor"`` strategy overrides this to accept the
-        (colors, seed) warm-start pair. Shapes derive from ``statics``
-        only, so state never breaks the zero-retrace guarantee."""
+        into the extra arguments this strategy's compiled program takes,
+        as host arrays (the plan uploads them with the graph). The base
+        strategies are stateless — any runtime kwarg is an error; the
+        ``"recolor"`` strategy overrides this to accept the (colors, seed)
+        warm-start pair. Shapes derive from ``statics`` only, so state
+        never breaks the zero-retrace guarantee."""
         if runtime:
             raise TypeError(
                 f"strategy {self.name!r} takes no per-call state; got "
@@ -689,21 +693,21 @@ class RecolorStrategy(ColoringStrategy):
                 "'natural' (got {!r})".format(spec.ordering))
         V = statics.num_vertices
         if colors is None:
-            colors_d = jnp.zeros((V,), jnp.int32)
+            colors_d = np.zeros((V,), np.int32)
         else:
             colors = np.asarray(colors)
             if colors.shape != (V,):
                 raise ValueError(f"recolor state: colors shape "
                                  f"{colors.shape} != ({V},)")
-            colors_d = jnp.asarray(colors.astype(np.int32))
+            colors_d = colors.astype(np.int32)
         if seed is None:
-            seed_d = jnp.ones((V,), jnp.bool_)
+            seed_d = np.ones((V,), np.bool_)
         else:
             seed = np.asarray(seed)
             if seed.shape != (V,):
                 raise ValueError(f"recolor state: seed shape "
                                  f"{seed.shape} != ({V},)")
-            seed_d = jnp.asarray(seed.astype(np.bool_))
+            seed_d = seed.astype(np.bool_)
         return colors_d, seed_d
 
 
@@ -812,13 +816,10 @@ class ColoringPlan:
         """Number of jit traces taken by this plan's program(s)."""
         return self._traces
 
-    def _canonicalize(self, g) -> Tuple[object, Optional[np.ndarray]]:
-        """Host graph -> (canonical input, relabel perm or None).
-
-        Lowers the model (square lowering: paddable, dedup'd), applies the
-        ordering relabel, pads edges to the bucket and pins every static
-        DeviceGraph field to the plan envelope so the jit cache key is
-        constant across served graphs."""
+    def _canonicalize(self, g) -> Tuple[Graph, Optional[np.ndarray]]:
+        """Host graph -> (constraint graph in plan ids, relabel perm or
+        None): lowers the model (square lowering: paddable, dedup'd),
+        applies the ordering relabel and checks the plan envelope."""
         spec, st = self.spec, self.statics
         host = constraint_host_graph(g, spec.model, side=spec.side)
         if host.num_vertices != st.num_vertices:
@@ -840,18 +841,30 @@ class ColoringPlan:
                 f"graph max degree {host.max_degree()} exceeds the plan "
                 f"bound {st.max_degree}; compile a plan with a larger "
                 "PlanShape.max_degree (the color tables would drop forbids)")
+        return host, perm
+
+    def _prepare(self, g) -> Tuple[object, Optional[np.ndarray]]:
+        """Host graph -> (program input, relabel perm or None): the
+        ``plan.canonicalize`` and ``plan.layout`` phases. Device strategies
+        get the canonical DeviceGraph with its arrays still on the host:
+        padded to the bucket, every static field pinned to the plan
+        envelope so the jit cache key is constant across served graphs.
+        Host strategies get the constraint Graph."""
+        with spans.span("plan.canonicalize"):
+            host, perm = self._canonicalize(g)
         if self.strategy.wants == "host":
             return host, perm
-        layout = ("edges", "ell") if self.backend.needs_ell else "edges"
-        dg = host.to_device(layout=layout, pad_edges_to=st.padded_edges,
-                            ell_width=max(1, st.max_degree))
-        # pin the static metadata to the envelope: num_directed_edges and
-        # max_degree are pytree aux data (= jit cache key), and the impls
-        # read them only to size color tables, for which the envelope bound
-        # is exactly as correct as the per-graph value
-        dg = dataclasses.replace(dg, num_directed_edges=st.padded_edges,
-                                 max_degree=st.max_degree)
-        return dg, perm
+        st = self.statics
+        with spans.span("plan.layout"):
+            layout = ("edges", "ell") if self.backend.needs_ell else "edges"
+            dg = host.host_layout(layout=layout,
+                                  pad_edges_to=st.padded_edges,
+                                  ell_width=max(1, st.max_degree))
+        # num_directed_edges and max_degree are pytree aux data (= jit cache
+        # key), and the impls read them only to size color tables, for which
+        # the envelope bound is exactly as correct as the per-graph value
+        return dataclasses.replace(dg, num_directed_edges=st.padded_edges,
+                                   max_degree=st.max_degree), perm
 
     def _finish(self, raw: RawColoring, perm: Optional[np.ndarray],
                 t0: float, *, batch_denom: int = 1) -> ColoringReport:
@@ -884,7 +897,7 @@ class ColoringPlan:
             raise NotImplementedError(
                 f"plan.lower needs a compiled device program; strategy "
                 f"{self.strategy.name!r} with this envelope has none")
-        canon, _ = self._canonicalize(g)
+        canon, _ = self._prepare(g)
         state = self.strategy.plan_state(self.spec, self.statics, **runtime)
         return self._executor.lower(canon, *state)
 
@@ -892,17 +905,33 @@ class ColoringPlan:
     def __call__(self, g, **runtime) -> ColoringReport:
         """Color ``g`` through the compiled program. ``runtime`` kwargs are
         per-call state for strategies that take it (``"recolor"``:
-        ``colors=``, ``seed=``); stateless strategies reject any."""
-        t0 = time.perf_counter()
-        canon, perm = self._canonicalize(g)
-        state = self.strategy.plan_state(self.spec, self.statics, **runtime)
-        if self._degenerate:  # validated above; nothing to run — but a
-            # recolor warm start keeps its committed colors (the strategy
-            # contract: non-seed vertices never change)
-            return _trivial_report(self.spec, self.statics.num_vertices, t0,
-                                   colors=runtime.get("colors"))
-        raw = self._executor(canon, *state)
-        return self._finish(raw, perm, t0)
+        ``colors=``, ``seed=``); stateless strategies reject any.
+
+        Each call leaves one ``plan.call`` record in
+        :mod:`repro.core.spans`; the report's ``wall_time_s`` is that
+        span's duration."""
+        with spans.call("plan.call") as root:
+            t0 = root.start_ns * 1e-9
+            canon, perm = self._prepare(g)
+            state = self.strategy.plan_state(self.spec, self.statics,
+                                             **runtime)
+            if self._degenerate:  # validated above; nothing to run — but a
+                # recolor warm start keeps its committed colors (the
+                # strategy contract: non-seed vertices never change)
+                report = _trivial_report(self.spec,
+                                         self.statics.num_vertices, t0,
+                                         colors=runtime.get("colors"))
+            else:
+                if self.strategy.wants == "device":
+                    with spans.span("plan.transfer"):
+                        canon, state = jax.block_until_ready(
+                            jax.device_put((canon, state)))
+                with spans.span("plan.device"):
+                    raw = jax.block_until_ready(self._executor(canon, *state))
+                with spans.span("plan.report"):
+                    report = self._finish(raw, perm, t0)
+        report.wall_time_s = root.duration_ns * 1e-9
+        return report
 
     def map(self, graphs: Sequence) -> list:
         """Color a batch of same-bucket graphs through ONE vmapped program.
@@ -918,7 +947,7 @@ class ColoringPlan:
         if not graphs:
             return []
         t0 = time.perf_counter()
-        canons, perms = zip(*(self._canonicalize(g) for g in graphs))
+        canons, perms = zip(*(self._prepare(g) for g in graphs))
         if self._degenerate:
             return [_trivial_report(self.spec, self.statics.num_vertices,
                                     t0, batch_denom=len(graphs))

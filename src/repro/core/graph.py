@@ -277,7 +277,8 @@ class Graph:
     def to_device(self, *, layout: Union[str, Sequence[str]] = "edges",
                   pad_edges_to: Optional[int] = None,
                   ell_width: Optional[int] = None) -> "DeviceGraph":
-        """Move the graph on device in the requested layout(s).
+        """Move the graph on device in the requested layout(s): the arrays
+        of :meth:`host_layout`, copied in one ``jax.device_put``.
 
         layout: ``"edges"`` (directed edge list — always present),
             ``"csr"`` (adds ``row_ptr``/``col_idx`` device arrays), ``"ell"``
@@ -291,6 +292,14 @@ class Graph:
             smaller width truncates rows and is only safe for callers that
             do not need exact neighborhoods).
         """
+        return jax.device_put(self.host_layout(
+            layout=layout, pad_edges_to=pad_edges_to, ell_width=ell_width))
+
+    def host_layout(self, *, layout: Union[str, Sequence[str]] = "edges",
+                    pad_edges_to: Optional[int] = None,
+                    ell_width: Optional[int] = None) -> "DeviceGraph":
+        """The :class:`DeviceGraph` that :meth:`to_device` uploads, its
+        arrays still numpy on the host (same arguments)."""
         layouts = (layout,) if isinstance(layout, str) else tuple(layout)
         unknown = set(layouts) - set(_LAYOUTS)
         if unknown:
@@ -306,11 +315,11 @@ class Graph:
         # into the (row-contiguous) directed edge list — one gather is all
         # the frontier layer needs to compact an active vertex set with its
         # incident constraint edges (repro.core.frontier.compact_frontier)
-        inc_ptr_dev = None
+        inc_ptr = None
         if self.num_directed_edges <= np.iinfo(np.int32).max:
-            inc_ptr_dev = jnp.asarray(self.row_ptr.astype(np.int32))
+            inc_ptr = self.row_ptr.astype(np.int32)
 
-        row_ptr_dev = col_idx_dev = slot_dev = None
+        row_ptr = col_idx = slot = None
         width = 0
         if "csr" in layouts:
             # device row_ptr is int32 (int64 would silently downcast under
@@ -318,8 +327,8 @@ class Graph:
             if self.num_directed_edges > np.iinfo(np.int32).max:
                 raise ValueError("csr device layout needs 2E < 2^31; "
                                  f"got {self.num_directed_edges} edges")
-            row_ptr_dev = jnp.asarray(self.row_ptr.astype(np.int32))
-            col_idx_dev = jnp.asarray(self.col_idx)
+            row_ptr = self.row_ptr.astype(np.int32)
+            col_idx = self.col_idx
         if "ell" in layouts:
             width = max(1, int(ell_width if ell_width is not None
                                else self.max_degree()))
@@ -329,7 +338,6 @@ class Graph:
             slot = np.minimum(pos, width).astype(np.int32)
             if pad:
                 slot = np.concatenate([slot, np.full(pad, width, np.int32)])
-            slot_dev = jnp.asarray(slot)
 
         if pad:
             # padding edges point at a phantom vertex V with src=V so they are
@@ -339,14 +347,14 @@ class Graph:
         return DeviceGraph(
             num_vertices=self.num_vertices,
             num_directed_edges=e,
-            src=jnp.asarray(src),
-            dst=jnp.asarray(dst),
+            src=src,
+            dst=dst,
             max_degree=self.max_degree(),
-            row_ptr=row_ptr_dev,
-            col_idx=col_idx_dev,
-            ell_slot=slot_dev,
+            row_ptr=row_ptr,
+            col_idx=col_idx,
+            ell_slot=slot,
             ell_width=width,
-            inc_ptr=inc_ptr_dev,
+            inc_ptr=inc_ptr,
         )
 
     def to_ell(self, max_degree: Optional[int] = None) -> Tuple[np.ndarray, np.ndarray]:
