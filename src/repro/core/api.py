@@ -189,6 +189,9 @@ class RawColoring(NamedTuple):
     frontier_per_round: jnp.ndarray   # [max_rounds] int32: active vertices
     # compacted in each round (0 = the round took the full-edge path; for
     # DATAFLOW, entry 0 counts the slab-compacted sweeps instead)
+    wavefront_spills: jnp.ndarray = 0  # scalar int32: offset classes of the
+    # full rounds whose forbidding edges overflowed the lockstep wavefront's
+    # window and took a full-edge pass (ITERATIVE; 0 elsewhere)
 
 
 def _invert_order(order: np.ndarray) -> np.ndarray:
@@ -221,6 +224,7 @@ def _build_report(raw: "RawColoring", spec: "ColoringSpec",
         sweeps_per_round=np.asarray(raw.sweeps_per_round)[:rounds],
         frontier_sizes_per_round=(
             np.asarray(raw.frontier_per_round)[:rounds]),
+        wavefront_spills=int(raw.wavefront_spills),
         wall_time_s=(time.perf_counter() - t0) / max(1, batch_denom),
         spec=spec)
 
@@ -266,7 +270,11 @@ class ColoringReport:
     entries. ``frontier_sizes_per_round[r]`` is the number of active
     vertices round r swept through the compacted frontier slab (0 = the
     round took the full-edge path; DATAFLOW reports its slab-compacted
-    sweep count in entry 0). ``wall_time_s`` covers lowering + execution +
+    sweep count in entry 0). ``sweeps_per_round[r]`` counts round r's
+    phase-1 passes over its edge set: a chaotic sweep is one; a lockstep
+    wavefront (ITERATIVE's full rounds, repro.core.iterative) is one plus
+    one full-edge pass per spilled offset class, and ``wavefront_spills``
+    sums those classes over the rounds. ``wall_time_s`` covers lowering + execution +
     host transfer: for ``plan(g)`` it is the ``plan.call`` span's duration
     (:mod:`repro.core.spans`); plan-batched runs report the amortized
     per-graph time.
@@ -285,6 +293,7 @@ class ColoringReport:
     spec: ColoringSpec
     frontier_sizes_per_round: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros(0, np.int32))
+    wavefront_spills: int = 0
 
     @functools.cached_property
     def num_colors(self) -> int:
@@ -440,13 +449,14 @@ class IterativeStrategy(ColoringStrategy):
                 spec.frontier, int(spec.frontier_capacity),
                 num_vertices=dg.num_vertices, padded_edges=dg.padded_edges,
                 max_degree=dg.max_degree, has_inc=dg.has_frontier)
-            colors, rnd, conf, sweeps, fronts, left = _iterative_impl(
+            colors, rnd, conf, sweeps, fronts, left, spills = _iterative_impl(
                 dg, concurrency=int(spec.concurrency),
                 max_rounds=int(spec.max_rounds),
                 max_sweeps=int(spec.max_sweeps), backend=backend,
                 color_bound=int(spec.color_bound),
                 frontier_cap_v=fcv, frontier_cap_e=fce)
-            return RawColoring(colors, rnd, conf, sweeps, left, fronts)
+            return RawColoring(colors, rnd, conf, sweeps, left, fronts,
+                               spills)
 
         return run
 
@@ -671,14 +681,15 @@ class RecolorStrategy(ColoringStrategy):
                 spec.frontier, int(spec.frontier_capacity),
                 num_vertices=dg.num_vertices, padded_edges=dg.padded_edges,
                 max_degree=dg.max_degree, has_inc=dg.has_frontier)
-            colors, rnd, conf, sweeps, fronts, left = _iterative_impl(
+            colors, rnd, conf, sweeps, fronts, left, spills = _iterative_impl(
                 dg, colors0, pending0, concurrency=int(spec.concurrency),
                 max_rounds=int(spec.max_rounds),
                 max_sweeps=int(spec.max_sweeps), backend=backend,
                 color_bound=int(spec.color_bound),
                 frontier_cap_v=fcv, frontier_cap_e=fce,
                 seed_frontier=True)
-            return RawColoring(colors, rnd, conf, sweeps, left, fronts)
+            return RawColoring(colors, rnd, conf, sweeps, left, fronts,
+                               spills)
 
         return run
 
@@ -930,6 +941,7 @@ class ColoringPlan:
                     raw = jax.block_until_ready(self._executor(canon, *state))
                 with spans.span("plan.report"):
                     report = self._finish(raw, perm, t0)
+            spans.count("wavefront_spills", report.wavefront_spills)
         report.wall_time_s = root.duration_ns * 1e-9
         return report
 

@@ -28,6 +28,10 @@ Each thread keeps its own stack of open spans, so concurrent calls from
 different threads never share a record. A span left by an exception is
 closed and marked ``error``.
 
+``count(name, value)`` adds to a counter of the open call (nothing outside
+a call): ``plan.call`` records ``wavefront_spills``, the report's count of
+offset classes that overflowed the lockstep wavefront's window.
+
 An operator reads the per-call breakdown, newest last, with
 :func:`recent_calls`::
 
@@ -47,7 +51,7 @@ import dataclasses
 import itertools
 import threading
 import time
-from typing import Callable, Iterator, List
+from typing import Callable, Dict, Iterator, List
 
 import jax
 
@@ -75,10 +79,11 @@ class SpanRecord:
 @dataclasses.dataclass
 class CallRecord:
     """The spans of one call, in the order they started; ``spans[0]`` is
-    the root."""
+    the root. ``counters`` holds what :func:`count` added during it."""
 
     call_id: int
     spans: List[SpanRecord] = dataclasses.field(default_factory=list)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def root(self) -> SpanRecord:
@@ -144,6 +149,14 @@ class Recorder:
             return jax.profiler.TraceAnnotation(name)
         return self._recorded(name, stack[-1][0])
 
+    def count(self, name: str, value: int) -> None:
+        """Add ``value`` to the open call's counter ``name``; outside a call
+        nothing is recorded."""
+        stack = self._stack()
+        if stack:
+            counters = stack[-1][0].counters
+            counters[name] = counters.get(name, 0) + int(value)
+
     @contextlib.contextmanager
     def call(self, name: str) -> Iterator[SpanRecord]:
         """The root span of a new call record; yields the root's
@@ -173,4 +186,5 @@ class Recorder:
 RECORDER = Recorder()
 span = RECORDER.span
 call = RECORDER.call
+count = RECORDER.count
 recent_calls = RECORDER.recent_calls
