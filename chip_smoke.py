@@ -10,11 +10,13 @@ Phases (one chip):
 * plan/sort      RMAT-G scale 20 through ``compile_plan`` -> ``plan(g)``
                  twice (iterative strategy, sort engine): valid coloring,
                  palette <= Delta+1, ``plan.traces == 1``;
-* kernel         RMAT-ER scale 20: ``engine="fused_pallas"`` against
-                 ``engine="sort"`` under the same spec — bit-identical
-                 colors, rounds and conflict history — with the Pallas
-                 kernel compiled (``tpu_custom_call`` in the plan's HLO,
-                 ``interpret`` resolved to False);
+* kernel         RMAT-ER and RMAT-B scale 20: ``engine="fused_pallas"``
+                 against ``engine="sort"`` under the same spec —
+                 bit-identical colors, rounds and conflict history — with
+                 the Pallas kernel compiled (``tpu_custom_call`` in the
+                 plan's HLO, ``interpret`` resolved to False); RMAT-B's
+                 hubs are above the slab's width, so its plan is binned
+                 and they take the per-edge hub path;
 * service        ``AsyncColoringService``: 8 requests from 2 tenants on
                  RMAT-G scale-16 graphs, micro-batched 4 at a time, plus one
                  streamed edge-delta batch; every response validated;
@@ -125,26 +127,34 @@ def phase_plan_sort() -> None:
 
 def phase_kernel() -> None:
     from repro.core import ColoringSpec, compile_plan, validate_coloring
-    g = rmat("RMAT-ER", KERNEL_SCALE)
-    spec = ColoringSpec(strategy="iterative", engine="sort",
-                        concurrency=CONCURRENCY, max_rounds=MAX_ROUNDS)
-    ref = compile_plan(spec, g)(g)
-    fused = compile_plan(dataclasses.replace(spec, engine="fused_pallas"), g)
-    words = -(-(g.max_degree() + 3) // 32)
-    print(f"  fused_pallas: words={words} interpret="
-          f"{fused.backend.interpret}", flush=True)
-    check(fused.backend.interpret is False,
-          "kernel resolved to compiled mode (interpret=False)")
-    hlo = fused.lower(g).compile().as_text()
-    check("tpu_custom_call" in hlo, "tpu_custom_call in the plan's HLO")
-    rep = fused(g)
-    print(f"  sort: colors={ref.num_colors} rounds={ref.rounds} "
-          f"conflicts={ref.conflicts_per_round.tolist()}; fused_pallas: "
-          f"colors={rep.num_colors} rounds={rep.rounds} "
-          f"({rep.wall_time_s:.1f}s, compile included)", flush=True)
-    check(validate_coloring(g, rep.colors), "fused_pallas coloring valid")
-    check(same_report(rep, ref),
-          "fused_pallas == sort: colors, rounds, conflict history")
+    for family in ("RMAT-ER", "RMAT-B"):
+        g = rmat(family, KERNEL_SCALE)
+        spec = ColoringSpec(strategy="iterative", engine="sort",
+                            concurrency=CONCURRENCY, max_rounds=MAX_ROUNDS)
+        ref = compile_plan(spec, g)(g)
+        fused = compile_plan(dataclasses.replace(spec,
+                                                 engine="fused_pallas"), g)
+        width = fused.backend.layout_width(g.max_degree())
+        print(f"  fused_pallas: ELL width {width} (max degree "
+              f"{g.max_degree()}) interpret={fused.backend.interpret}",
+              flush=True)
+        check(fused.backend.interpret is False,
+              "kernel resolved to compiled mode (interpret=False)")
+        hlo = fused.lower(g).compile().as_text()
+        check("tpu_custom_call" in hlo, "tpu_custom_call in the plan's HLO")
+        rep = fused(g)
+        print(f"  sort: colors={ref.num_colors} rounds={ref.rounds} "
+              f"conflicts={ref.conflicts_per_round.tolist()}; fused_pallas: "
+              f"colors={rep.num_colors} rounds={rep.rounds} hub rows="
+              f"{rep.hub_rows} ({rep.wall_time_s:.1f}s, compile included)",
+              flush=True)
+        check(validate_coloring(g, rep.colors),
+              f"{family} fused_pallas coloring valid")
+        check(same_report(rep, ref), f"{family} fused_pallas == sort: "
+              "colors, rounds, conflict history")
+        if width < g.max_degree():
+            check(rep.hub_rows > 0 and "hub_mex" in hlo,
+                  f"{family}: rows above width {width} took the hub path")
 
 
 def phase_service() -> None:

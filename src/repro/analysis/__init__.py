@@ -91,7 +91,7 @@ class AnalysisConfig:
     baseline_path: Optional[str] = None
 
 
-def _abstract_device_graph(statics, *, needs_ell: bool):
+def _abstract_device_graph(statics, backend):
     """A :class:`~repro.core.graph.DeviceGraph` of ``ShapeDtypeStruct``
     leaves matching the plan envelope — enough to ``jax.make_jaxpr`` the
     plan program without any concrete graph. ``inc_ptr`` is always present
@@ -109,8 +109,8 @@ def _abstract_device_graph(statics, *, needs_ell: bool):
         num_vertices=V, num_directed_edges=E,
         src=sds((E,), jnp.int32), dst=sds((E,), jnp.int32),
         max_degree=int(statics.max_degree),
-        ell_slot=sds((E,), jnp.int32) if needs_ell else None,
-        ell_width=D if needs_ell else 0,
+        ell_slot=sds((E,), jnp.int32) if backend.needs_ell else None,
+        ell_width=backend.layout_width(D) if backend.needs_ell else 0,
         inc_ptr=sds((V + 1,), jnp.int32))
 
 
@@ -154,7 +154,7 @@ def trace_plan_program(spec, statics):
             return jax.make_jaxpr(fn)(shaped, shaped, bshaped)
 
     prog = strategy.device_program(spec, backend)
-    dg = _abstract_device_graph(statics, needs_ell=backend.needs_ell)
+    dg = _abstract_device_graph(statics, backend)
     if spec.strategy == "recolor":
         return jax.make_jaxpr(prog)(dg, sds((V,), jnp.int32),
                                     sds((V,), jnp.bool_))

@@ -274,7 +274,12 @@ class ColoringReport:
     phase-1 passes over its edge set: a chaotic sweep is one; a lockstep
     wavefront (ITERATIVE's full rounds, repro.core.iterative) is one plus
     one full-edge pass per spilled offset class, and ``wavefront_spills``
-    sums those classes over the rounds. ``wall_time_s`` covers lowering + execution +
+    sums those classes over the rounds. ``hub_rows`` and ``hub_edges``
+    count the rows, and their directed edge entries (each the forbid of a
+    neighbour's colour), that a plan's binned ELL layout leaves to the
+    ``fused_pallas`` engine's per-edge hub path: the rows longer than the
+    slab (:data:`repro.core.engine.HUB_WIDTH`); 0 where the layout is not
+    binned. ``wall_time_s`` covers lowering + execution +
     host transfer: for ``plan(g)`` it is the ``plan.call`` span's duration
     (:mod:`repro.core.spans`); plan-batched runs report the amortized
     per-graph time.
@@ -294,6 +299,8 @@ class ColoringReport:
     frontier_sizes_per_round: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros(0, np.int32))
     wavefront_spills: int = 0
+    hub_rows: int = 0
+    hub_edges: int = 0
 
     @functools.cached_property
     def num_colors(self) -> int:
@@ -854,33 +861,49 @@ class ColoringPlan:
                 "PlanShape.max_degree (the color tables would drop forbids)")
         return host, perm
 
-    def _prepare(self, g) -> Tuple[object, Optional[np.ndarray]]:
-        """Host graph -> (program input, relabel perm or None): the
-        ``plan.canonicalize`` and ``plan.layout`` phases. Device strategies
-        get the canonical DeviceGraph with its arrays still on the host:
-        padded to the bucket, every static field pinned to the plan
-        envelope so the jit cache key is constant across served graphs.
-        Host strategies get the constraint Graph."""
+    def _prepare(self, g) -> Tuple[object, Optional[np.ndarray],
+                                   Tuple[int, int]]:
+        """Host graph -> (program input, relabel perm or None, (hub rows,
+        hub edges)): the ``plan.canonicalize`` and ``plan.layout`` phases.
+        Device strategies get the canonical DeviceGraph with its arrays
+        still on the host: padded to the bucket, every static field pinned
+        to the plan envelope so the jit cache key is constant across served
+        graphs, its ELL layout as wide as the backend asks for the envelope
+        (:meth:`MexBackend.layout_width`), with the rows a binned layout
+        leaves to the hub path counted. Host strategies get the
+        constraint Graph."""
         with spans.span("plan.canonicalize"):
             host, perm = self._canonicalize(g)
         if self.strategy.wants == "host":
-            return host, perm
+            return host, perm, (0, 0)
         st = self.statics
+        hub = (0, 0)
         with spans.span("plan.layout"):
-            layout = ("edges", "ell") if self.backend.needs_ell else "edges"
-            dg = host.host_layout(layout=layout,
-                                  pad_edges_to=st.padded_edges,
-                                  ell_width=max(1, st.max_degree))
+            if self.backend.needs_ell:
+                width = self.backend.layout_width(st.max_degree)
+                dg = host.host_layout(layout=("edges", "ell"),
+                                      pad_edges_to=st.padded_edges,
+                                      ell_width=width)
+                if width < st.max_degree:
+                    deg = host.degrees()
+                    rows = deg > width
+                    hub = (int(rows.sum()), int(deg[rows].sum()))
+            else:
+                dg = host.host_layout(layout="edges",
+                                      pad_edges_to=st.padded_edges)
         # num_directed_edges and max_degree are pytree aux data (= jit cache
         # key), and the impls read them only to size color tables, for which
         # the envelope bound is exactly as correct as the per-graph value
         return dataclasses.replace(dg, num_directed_edges=st.padded_edges,
-                                   max_degree=st.max_degree), perm
+                                   max_degree=st.max_degree), perm, hub
 
     def _finish(self, raw: RawColoring, perm: Optional[np.ndarray],
-                t0: float, *, batch_denom: int = 1) -> ColoringReport:
-        return _build_report(raw, self.spec, self.strategy.name, perm, t0,
-                             batch_denom=batch_denom)
+                t0: float, hub: Tuple[int, int], *,
+                batch_denom: int = 1) -> ColoringReport:
+        report = _build_report(raw, self.spec, self.strategy.name, perm, t0,
+                               batch_denom=batch_denom)
+        report.hub_rows, report.hub_edges = hub
+        return report
 
     # ----------------------------------------------------------- introspection
     def wire_cost(self) -> Optional[dict]:
@@ -908,7 +931,7 @@ class ColoringPlan:
             raise NotImplementedError(
                 f"plan.lower needs a compiled device program; strategy "
                 f"{self.strategy.name!r} with this envelope has none")
-        canon, _ = self._prepare(g)
+        canon, _, _ = self._prepare(g)
         state = self.strategy.plan_state(self.spec, self.statics, **runtime)
         return self._executor.lower(canon, *state)
 
@@ -923,7 +946,7 @@ class ColoringPlan:
         span's duration."""
         with spans.call("plan.call") as root:
             t0 = root.start_ns * 1e-9
-            canon, perm = self._prepare(g)
+            canon, perm, hub = self._prepare(g)
             state = self.strategy.plan_state(self.spec, self.statics,
                                              **runtime)
             if self._degenerate:  # validated above; nothing to run — but a
@@ -940,8 +963,10 @@ class ColoringPlan:
                 with spans.span("plan.device"):
                     raw = jax.block_until_ready(self._executor(canon, *state))
                 with spans.span("plan.report"):
-                    report = self._finish(raw, perm, t0)
+                    report = self._finish(raw, perm, t0, hub)
             spans.count("wavefront_spills", report.wavefront_spills)
+            spans.count("hub_rows", report.hub_rows)
+            spans.count("hub_edges", report.hub_edges)
         report.wall_time_s = root.duration_ns * 1e-9
         return report
 
@@ -959,7 +984,7 @@ class ColoringPlan:
         if not graphs:
             return []
         t0 = time.perf_counter()
-        canons, perms = zip(*(self._prepare(g) for g in graphs))
+        canons, perms, hubs = zip(*(self._prepare(g) for g in graphs))
         if self._degenerate:
             return [_trivial_report(self.spec, self.statics.num_vertices,
                                     t0, batch_denom=len(graphs))
@@ -971,7 +996,7 @@ class ColoringPlan:
         raws = self._batched(stacked)
         return [
             self._finish(jax.tree.map(lambda x, i=i: x[i], raws), perms[i],
-                         t0, batch_denom=len(graphs))
+                         t0, hubs[i], batch_denom=len(graphs))
             for i in range(len(graphs))
         ]
 

@@ -89,9 +89,12 @@ def _dataflow_impl(g: DeviceGraph, *, max_sweeps: int, backend,
     # compact the dependents' rows, run the mex over that slab. Both sets
     # spill to the full sweep when they overflow the static capacities, so
     # iterates (and the sweep count) stay bit-identical to the full path.
+    # A binned ELL layout's hub rows take the per-edge path in the slab
+    # too (repro.core.frontier.compact_frontier).
+    hub = g.hub_width if backend.needs_ell else 0
     mex_slab = backend.bind_slab(
         capacity=frontier_cap_v, max_colors=max_colors,
-        ell_width=g.max_degree, max_degree=g.max_degree)
+        ell_width=hub or g.max_degree, max_degree=g.max_degree)
     cap_v, cap_e = frontier_cap_v, frontier_cap_e
 
     def full_sweep(cpad):
@@ -110,7 +113,8 @@ def _dataflow_impl(g: DeviceGraph, *, max_sweeps: int, backend,
         nv, ne = frontier_counts(active, g.inc_ptr)
 
         def slab_sweep(cpad):
-            slab = compact_frontier(active, g.inc_ptr, g.dst, cap_v, cap_e)
+            slab = compact_frontier(active, g.inc_ptr, g.dst, cap_v, cap_e,
+                                    hub_width=hub)
             forb = (slab.src < V) & (slab.dst < slab.src)
             key_c = jnp.where(forb, cpad[slab.dst], 0)
             mexv = mex_slab(jnp.where(forb, slab.owner, cap_v), key_c,

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mex import segment_mex
+from .mex import hub_mex, segment_mex
 
 # A bound mex engine: (key_v [M], key_c [M]) -> mex [V] int32 (>= 1).
 # key_v[i] is the vertex the edge forbids (num_vertices = inert padding);
@@ -62,6 +62,14 @@ MexFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 SlabMexFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
+
+# The ``fused_pallas`` engine's ELL slab width where a plan's max degree is
+# above it (a binned layout, :meth:`MexBackend.layout_width`). Rows of
+# degree at most this keep the slab and the ``round_fused`` kernel, whose
+# bitset is sized from it; longer rows (hubs) take the per-edge
+# :func:`repro.core.mex.hub_mex`, whose work grows with their edges. The
+# slab's memory grows with it, the hub edges' share falls with it.
+HUB_WIDTH = 256
 
 
 class SweepSpec(NamedTuple):
@@ -169,6 +177,13 @@ class MexBackend:
         mex = self.bind(num_vertices=capacity, max_colors=max_colors,
                         max_degree=max_degree)
         return lambda key_v, key_c, slot: mex(key_v, key_c)
+
+    def layout_width(self, max_degree: int) -> int:
+        """The ELL width a plan lays its graphs out at for this backend,
+        from the envelope's max degree: the full width. A narrower width
+        makes a binned layout (:meth:`repro.core.graph.Graph.host_layout`),
+        which only an engine with a hub path accepts."""
+        return max(1, int(max_degree))
 
     def resolved(self) -> "MexBackend":
         """This backend with its call-time defaults decided — a Pallas
@@ -320,12 +335,21 @@ class FusedPallasMexBackend(MexBackend):
     ``ell_pallas`` scatter — the engine protocol pre-masks sweeps by
     precedence, so the drivers consume only the mex lane here (the conflict
     lane stays inert: no CONFLICT bits are packed and ``own_colors`` is 0).
-    The full detect→mex→assign fusion over live colors is exercised and
-    measured by ``benchmarks/roofline.py --round``.
+    Its share of the HBM roofline on the chip is the benchmark's
+    ``round_fused_roofline`` (``bench/``).
+
+    A plan whose max degree is above :data:`HUB_WIDTH` lays its graphs out
+    binned (:meth:`layout_width`): the slab is ``HUB_WIDTH`` wide, and
+    every edge of a longer row carries the marker slot ``HUB_WIDTH``, which
+    the slab scatter drops. Those hub rows take the per-edge
+    :func:`repro.core.mex.hub_mex` instead, and a row's mex is the larger
+    of the two paths' (the path a row does not take reads 1). So the
+    slab, the kernel's bitset and the host slot map are sized from the
+    bin, not from the max degree.
 
     Bit-identical to ``"bitmap"``/``"ell_pallas"`` by construction: same
-    forbidden bitset (color 0 pre-set, out-of-range colors drop), same
-    min-free-bit scan.
+    forbidden bitset (color 0 pre-set, out-of-range colors drop — a slab
+    row's mex is at most its width + 1), same min-free-bit scan.
     """
 
     name = "fused_pallas"
@@ -333,6 +357,22 @@ class FusedPallasMexBackend(MexBackend):
     needs_color_bound = True
     words: Optional[int] = None
     interpret: Optional[bool] = None
+
+    def layout_width(self, max_degree: int) -> int:
+        return max(1, min(int(max_degree), HUB_WIDTH))
+
+    def _slab_width(self, width: int, max_degree: int, what: str) -> int:
+        """The slab width of a layout ``width`` wide, checked: the full
+        max degree, or this engine's bin below it. A narrower slab of any
+        other width would have been truncated, not binned."""
+        D = max(1, int(width))
+        if max_degree > D and D != self.layout_width(max_degree):
+            raise ValueError(
+                f"fused_pallas {what}: ELL width {D} is below the graph's "
+                f"max degree {max_degree} and is not the engine's bin "
+                f"width {self.layout_width(max_degree)}; rebuild with "
+                "Graph.to_device(layout='ell') (full width)")
+        return D
 
     def bind(self, *, num_vertices: int, max_colors: int = 0,
              ell_slot=None, ell_width: int = 0, max_degree: int = -1) -> MexFn:
@@ -344,14 +384,11 @@ class FusedPallasMexBackend(MexBackend):
                 "with Graph.to_device(layout='ell') (or compute edge_slots "
                 "for a custom partition)")
         required = max_degree if max_degree >= 0 else max_colors - 1
-        if required > 0 and ell_width < required:
-            raise ValueError(
-                f"fused_pallas engine: ELL width {ell_width} is below the "
-                f"graph's max degree {required}; rebuild with "
-                "Graph.to_device(layout='ell') (full width)")
         V = num_vertices
-        D = max(1, int(ell_width))
-        words = _resolve_words(self.words, max_colors, self.name)
+        D = self._slab_width(ell_width, required, "bind")
+        hub = required > D
+        words = _resolve_words(self.words, min(max_colors, D + 1) if hub
+                               else max_colors, self.name)
         interp = resolve_interpret(self.interpret)
         from ..kernels.round_fused import FORBID_BIT, round_fused
 
@@ -360,6 +397,9 @@ class FusedPallasMexBackend(MexBackend):
                    .at[key_v, ell_slot].set(key_c | FORBID_BIT, mode="drop"))
             m, _ = round_fused(ent[:V], jnp.zeros((V,), jnp.int32),
                                words=words, interpret=interp)
+            if hub:
+                m = jnp.maximum(m, hub_mex(
+                    jnp.where(ell_slot == D, key_v, V), key_c, V))
             return m
 
         return mex
@@ -368,16 +408,16 @@ class FusedPallasMexBackend(MexBackend):
                   ell_width: int = 0, max_degree: int = -1) -> SlabMexFn:
         """Frontier bind: the compacted (capacity, D) entry slab scatters
         through the per-round ``slot`` operand, mirroring the ell_pallas
-        slab bind."""
+        slab bind. Below the max degree, an edge whose ``slot`` is the
+        width belongs to a hub row and takes the per-edge path."""
         from ..kernels.ops import resolve_interpret
         from ..kernels.round_fused import FORBID_BIT, round_fused
 
-        D = max(1, int(ell_width if ell_width > 0 else max_degree))
-        if max_degree > D:
-            raise ValueError(
-                f"fused_pallas slab bind: width {D} is below the graph's max "
-                f"degree {max_degree}; a frontier row would drop forbids")
-        words = _resolve_words(self.words, max_colors, self.name)
+        D = self._slab_width(ell_width if ell_width > 0 else max_degree,
+                             max_degree, "slab bind")
+        hub = max_degree > D
+        words = _resolve_words(self.words, min(max_colors, D + 1) if hub
+                               else max_colors, self.name)
         interp = resolve_interpret(self.interpret)
         cap = int(capacity)
 
@@ -386,6 +426,9 @@ class FusedPallasMexBackend(MexBackend):
                    .at[key_v, slot].set(key_c | FORBID_BIT, mode="drop"))
             m, _ = round_fused(ent[:cap], jnp.zeros((cap,), jnp.int32),
                                words=words, interpret=interp)
+            if hub:
+                m = jnp.maximum(m, hub_mex(
+                    jnp.where(slot == D, key_v, cap), key_c, cap))
             return m
 
         return mex

@@ -112,7 +112,8 @@ class FrontierSlab(NamedTuple):
     dst:   [cap_e] int32 edge target in the *original* dst id space
            (global ids under the distributed driver); ``dst_pad`` = pad.
     slot:  [cap_e] int32 position of the edge within its row (the ELL slot
-           the ``ell_pallas`` slab bind scatters through).
+           the ``ell_pallas`` slab bind scatters through); in a binned
+           slab, the marker ``hub_width`` on every edge of a hub row.
     nv/ne: scalar int32 true active counts — may EXCEED the capacities;
            callers must spill to the full path when they do.
     """
@@ -138,7 +139,8 @@ def frontier_counts(active: jnp.ndarray,
 
 def compact_frontier(active: jnp.ndarray, inc_ptr: jnp.ndarray,
                      dst: jnp.ndarray, cap_v: int, cap_e: int,
-                     *, dst_pad: Optional[int] = None) -> FrontierSlab:
+                     *, dst_pad: Optional[int] = None,
+                     hub_width: int = 0) -> FrontierSlab:
     """Compact the active vertices and their incident CSR rows into a
     :class:`FrontierSlab` — no sort: a rank cumsum places vertices, a
     degree cumsum + scatter + running max assigns edges to rows, and one
@@ -148,7 +150,10 @@ def compact_frontier(active: jnp.ndarray, inc_ptr: jnp.ndarray,
     (rows must be contiguous — true of every ``Graph.to_device`` edge list
     and of ``partition_graph`` slabs); ``dst`` the edge-target array the
     rows index; ``dst_pad`` the sentinel for padded slab edges (defaults to
-    V — the distributed driver passes its global phantom id instead).
+    V — the distributed driver passes its global phantom id instead);
+    ``hub_width`` > 0 bins the slab as the graph's ELL layout is binned
+    (:attr:`repro.core.graph.DeviceGraph.hub_width`): every edge of a row
+    longer than it gets the slot ``hub_width``, the hub path's marker.
 
     Overflow never corrupts: rows landing beyond the capacities are dropped
     by the scatters, and ``nv``/``ne`` report the TRUE counts so callers
@@ -185,6 +190,8 @@ def compact_frontier(active: jnp.ndarray, inc_ptr: jnp.ndarray,
         gdst = dst[jnp.clip(gidx, 0, dst.shape[0] - 1)]
     else:  # no edges: every slab edge is padding, and there is no
         gdst = jnp.full((cap_e,), fill, jnp.int32)  # element to gather
+    if hub_width:
+        slot = jnp.where(vdeg[owner] > hub_width, hub_width, slot)
     return FrontierSlab(
         vert=vert,
         owner=jnp.where(valid, owner, cap_v),

@@ -288,9 +288,12 @@ class Graph:
             :meth:`to_ell`, since the engine rebuilds color slabs per sweep
             and never reads neighbor ids from device), or any sequence of
             these. Backends pick what they need from the result.
-        ell_width: optional ELL width override (default: max degree; a
-            smaller width truncates rows and is only safe for callers that
-            do not need exact neighborhoods).
+        ell_width: optional ELL width override (default: max degree). A
+            smaller width makes a binned layout: every edge of a row longer
+            than the width gets the slot ``width``, which slab scatters
+            drop, so such a row (a hub) is left out of the slab whole. Only
+            the ``fused_pallas`` engine, whose hub path takes those edges,
+            accepts it (:attr:`DeviceGraph.hub_width`).
         """
         return jax.device_put(self.host_layout(
             layout=layout, pad_edges_to=pad_edges_to, ell_width=ell_width))
@@ -307,6 +310,7 @@ class Graph:
                              f"choose from {_LAYOUTS}")
         src, dst = self.directed_edges()
         e = src.shape[0]
+        max_degree = self.max_degree()
         pad = (pad_edges_to or e) - e
         if pad < 0:
             raise ValueError(f"pad_edges_to={pad_edges_to} < num edges {e}")
@@ -331,11 +335,16 @@ class Graph:
             col_idx = self.col_idx
         if "ell" in layouts:
             width = max(1, int(ell_width if ell_width is not None
-                               else self.max_degree()))
-            # slot of each edge within its row; out-of-width and padding
-            # edges get ``width`` so ELL scatters drop them (mode="drop")
+                               else max_degree))
+            # slot of each edge within its row; every edge of a row longer
+            # than the width, and padding edges, get ``width`` so ELL
+            # scatters drop them (mode="drop")
             pos = np.arange(e, dtype=np.int64) - self.row_ptr[src]
-            slot = np.minimum(pos, width).astype(np.int32)
+            if width < max_degree:
+                hub = self.degrees() > width
+                slot = np.where(hub[src], width, pos).astype(np.int32)
+            else:
+                slot = np.minimum(pos, width).astype(np.int32)
             if pad:
                 slot = np.concatenate([slot, np.full(pad, width, np.int32)])
 
@@ -349,7 +358,7 @@ class Graph:
             num_directed_edges=e,
             src=src,
             dst=dst,
-            max_degree=self.max_degree(),
+            max_degree=max_degree,
             row_ptr=row_ptr,
             col_idx=col_idx,
             ell_slot=slot,
@@ -487,6 +496,15 @@ class DeviceGraph:
     @property
     def has_ell(self) -> bool:
         return self.ell_slot is not None
+
+    @property
+    def hub_width(self) -> int:
+        """The slab width of a binned ELL layout, else 0: rows longer than
+        it are hub rows, every edge of which has the slot ``hub_width``
+        (:meth:`Graph.host_layout`)."""
+        if self.has_ell and 0 < self.ell_width < self.max_degree:
+            return self.ell_width
+        return 0
 
     @property
     def has_frontier(self) -> bool:

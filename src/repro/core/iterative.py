@@ -69,6 +69,7 @@ from .engine import (EngineSpec, MexFn, SlabMexFn, SweepSpec,
 from .frontier import (compact_frontier, frontier_conflicts, frontier_counts,
                        frontier_sweep)
 from .graph import DeviceGraph, pad_bucket
+from .mex import hub_mex
 
 # A full round's phase 1 runs as a lockstep wavefront while the envelope
 # holds at least this many directed edges per static offset class
@@ -135,17 +136,28 @@ def wavefront_capacities(num_vertices: int, padded_edges: int,
     classes = -(-V // P)
     cap_v = pad_bucket(min(P, V), min_bucket=8)
     if (classes * WAVEFRONT_EDGES_PER_CLASS > E or classes > int(max_sweeps)
-            or (classes + 1) * cap_v >= 2 ** 31):  # the int32 sort key
+            # the int32 sort key, whose hub segments (a binned layout)
+            # follow the classes
+            or (2 * classes + 1) * cap_v >= 2 ** 31):
         return 0, 0
     cap_e = max(-(-5 * E // (4 * classes)), 2 * max(0, int(max_degree)))
     return cap_v, min(E, pad_bucket(cap_e, min_bucket=8))
+
+
+def hub_windows(cap_e: int) -> Tuple[int, ...]:
+    """The static sizes a wavefront step's hub window takes, smallest
+    first: quarters down from ``cap_e``, so a step sorts at most four
+    times its class's hub edges (or the smallest size), not ``cap_e``."""
+    return tuple(sorted({min(cap_e, max(8, cap_e >> s))
+                         for s in (6, 4, 2, 0)}))
 
 
 def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
                        src: jnp.ndarray, dst: jnp.ndarray,
                        slot: Optional[jnp.ndarray], colors: jnp.ndarray,
                        pending: jnp.ndarray, concurrency: int,
-                       cap_v: int, cap_e: int, max_sweeps: int):
+                       cap_v: int, cap_e: int, max_sweeps: int,
+                       hub_width: int = 0):
     """Phase 1 of a full round in the paper's own schedule: offset class
     0, then 1, ..., then ``bs-1``, one step each.
 
@@ -168,8 +180,17 @@ def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
     runs both of its branches, a while loop only as long as some graph of
     the batch needs it.
 
+    On a binned layout (``hub_width`` > 0, the edges of hub rows carrying
+    the slot ``hub_width``) the hub rows' forbidding edges sort after
+    every class, in class order, and step k also takes its class's hub
+    edges in a window of their own, sized from :func:`hub_windows` by
+    their count, for :func:`repro.core.mex.hub_mex`; a thread's colour is
+    the larger of the two mexes. A class spills when either part is over
+    ``cap_e``.
+
     ``mex`` is the engine bound to the whole graph, ``mex_slab`` bound to
-    ``cap_v`` rows; ``slot`` is the ELL slot of each edge (``None`` for
+    ``cap_v`` rows (of the slab's width alone where the layout is
+    binned); ``slot`` is the ELL slot of each edge (``None`` for
     layout-free engines); ``colors`` holds 0 at every pending vertex;
     ``max_sweeps`` caps the sweeps after a spill. Returns ``(colors,
     passes, spills)``: ``passes`` is 1 (the steps read each forbidding
@@ -188,13 +209,17 @@ def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
     rs, rd = rpad[src], rpad[dst]
     # a neighbour forbids src iff committed, or pending at a smaller offset
     forbids = (rs >= 0) & ((rd < 0) | (rd % bs < rs % bs))
-    key = jnp.where(forbids, (rs % bs) * cap_v + rs // bs,
-                    num_classes * cap_v)
+    cls, segments = rs % bs, num_classes
+    if hub_width:
+        # hub rows' edges sort after every class: segment num_classes + k
+        cls, segments = (jnp.where(slot == hub_width, cls + num_classes, cls),
+                         2 * num_classes)
+    key = jnp.where(forbids, cls * cap_v + rs // bs, segments * cap_v)
     ops = lax.sort((key, dst) if slot is None else (key, dst, slot),
                    num_keys=1, is_stable=False)
     skey, sdst = ops[0], ops[1]
     starts = jnp.searchsorted(
-        skey, jnp.arange(num_classes + 1, dtype=jnp.int32) * cap_v
+        skey, jnp.arange(segments + 1, dtype=jnp.int32) * cap_v
     ).astype(jnp.int32)
     # the pending vertex of each rank; thread t's vertex at offset k has
     # rank t*bs + k
@@ -216,7 +241,32 @@ def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
         wslot = (win if slot is None
                  else lax.dynamic_slice(ops[2], (s,), (cap_e,)))
         m = mex_slab(jnp.where(live, row, cap_v), cpad[wdst], wslot)
+        if hub_width:
+            m = jnp.maximum(m, hub_step(k, cpad))
         return cpad.at[vert].set(m, mode="drop")
+
+    def hub_window(size):
+        def run(k, cpad):
+            lo = starts[num_classes + k]
+            n = starts[num_classes + k + 1] - lo
+            s = jnp.minimum(lo, M - size)
+            w = jnp.arange(size, dtype=jnp.int32)
+            live = (w >= lo - s) & (w < lo - s + n)
+            row = (lax.dynamic_slice(skey, (s,), (size,))
+                   - (num_classes + k) * cap_v)
+            return hub_mex(jnp.where(live, row, cap_v),
+                           cpad[lax.dynamic_slice(sdst, (s,), (size,))],
+                           cap_v)
+        return run
+
+    sizes = hub_windows(cap_e)
+    branches = [hub_window(z) for z in sizes]
+
+    def hub_step(k, cpad):
+        # the smallest window that holds the class's hub edges
+        n = starts[num_classes + k + 1] - starts[num_classes + k]
+        which = (n > jnp.asarray(sizes[:-1], jnp.int32)).sum()
+        return lax.switch(which, branches, k, cpad)
 
     cpad0 = jnp.concatenate([colors, jnp.zeros((1,), jnp.int32)])
     cpad = lax.fori_loop(0, bs, step, cpad0)
@@ -258,6 +308,9 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
     mex = backend.bind(num_vertices=V, max_colors=max_colors,
                        ell_slot=g.ell_slot, ell_width=g.ell_width,
                        max_degree=g.max_degree)
+    # a binned ELL layout's slab width: rows longer than it (hubs) take
+    # the per-edge path (repro.core.mex.hub_mex), 0 where none is binned
+    hub = g.hub_width if backend.needs_ell else 0
     # phase 1 of a full round in lockstep offset order where the static
     # class count allows (lockstep_wavefront), else chaotic sweeps
     wave_v, wave_e = wavefront_capacities(
@@ -266,10 +319,13 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
     if wavefront_cap_e >= 0:
         wave_e = min(int(wavefront_cap_e), g.padded_edges) if wave_v else 0
     if wave_e:
+        # binned, the steps take hub edges in windows of their own, so the
+        # step's slab binds the slab's rows alone
         wave_slab = backend.bind_slab(
-            capacity=wave_v, max_colors=max_colors,
+            capacity=wave_v,
+            max_colors=min(max_colors, hub + 1) if hub else max_colors,
             ell_width=g.ell_width if g.has_ell else g.max_degree,
-            max_degree=g.max_degree)
+            max_degree=hub or g.max_degree)
     # frontier execution layer (repro.core.frontier): rounds >= 1 whose
     # pending set fits the static slab run compacted — O(cap) per sweep
     # instead of O(E) — with a bit-identical spill to the full path
@@ -277,7 +333,7 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
     if use_frontier:
         mex_slab = backend.bind_slab(
             capacity=frontier_cap_v, max_colors=max_colors,
-            ell_width=g.max_degree, max_degree=g.max_degree)
+            ell_width=hub or g.max_degree, max_degree=g.max_degree)
 
     def round_body(state):
         (colors, pending, rnd, conf_hist, sweep_hist, front_hist,
@@ -296,7 +352,7 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
                         g.ell_slot if backend.needs_ell else None),
                     colors=jnp.where(pending, 0, colors), pending=pending,
                     concurrency=concurrency, cap_v=wave_v, cap_e=wave_e,
-                    max_sweeps=max_sweeps)
+                    max_sweeps=max_sweeps, hub_width=hub)
             else:
                 # neighbor forbids src iff committed, or pending at smaller
                 # offset
@@ -319,7 +375,8 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
             # and every constraint edge incident to one, so phase 1's
             # fixpoint and phase 2's conflict pass are bit-identical
             slab = compact_frontier(pending, g.inc_ptr, dst,
-                                    frontier_cap_v, frontier_cap_e)
+                                    frontier_cap_v, frontier_cap_e,
+                                    hub_width=hub)
             forbid_e = ((slab.src < V)
                         & (~ppad[slab.dst] | (opad[slab.dst] < opad[slab.src])))
             cpad0 = (jnp.concatenate([colors, jnp.zeros((1,), jnp.int32)])

@@ -5,8 +5,10 @@ This is the computational core of the ``"sort"`` :class:`~repro.core.engine.
 MexBackend` (the registry's layout-free default); the other registered
 backends (``"bitmap"``, ``"ell_pallas"``) compute the same function through
 different formulations — see ``repro.core.engine`` for the registry and
-DESIGN.md §Engine for the parity contract. Drivers never call this module
-directly: they go through ``MexBackend.bind(...)``'s returned mex closure.
+DESIGN.md §Engine for the parity contract. Drivers go through
+``MexBackend.bind(...)``'s returned mex closure; the one exception is
+:func:`hub_mex`, the per-edge path of a binned ELL layout's hub rows, which
+the lockstep wavefront calls on its own hub windows.
 
 Given a multiset of (vertex, forbidden-color) pairs, compute per vertex the
 minimum *positive* integer not present. The trick: lexicographically sort the
@@ -24,6 +26,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src.lax.lax import _sort_lower
+from jax.extend.core import Primitive
+from jax.interpreters import batching, mlir
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -45,3 +50,64 @@ def segment_mex(vertex: jnp.ndarray, color: jnp.ndarray, num_vertices: int) -> j
     cand = jnp.where(gap, c_s + 1, _INT32_MAX)
     mex = jax.ops.segment_min(cand, v_s, num_segments=num_vertices + 1)
     return mex[:num_vertices]
+
+
+# The hub path's sort as a primitive of its own. XLA names an instruction
+# after the primitive that emitted it, so this sort shows in a device trace
+# as ``hub_mex.N`` where a ``lax.sort`` shows as ``sort.N`` like every other
+# sort of the program; the benchmark's ``hub_mex_ms`` reads it by that name.
+# It lowers and batches exactly as ``lax.sort``.
+_hub_sort_p = Primitive("hub_mex")
+_hub_sort_p.multiple_results = True
+_hub_sort_p.def_abstract_eval(lambda *avals, **params: list(avals))
+_hub_sort_p.def_impl(lambda *ops, dimension, is_stable, num_keys: lax.sort(
+    ops, dimension=dimension, is_stable=is_stable, num_keys=num_keys))
+mlir.register_lowering(_hub_sort_p, _sort_lower)
+
+
+def _hub_sort_batch(args, dims, *, dimension, is_stable, num_keys):
+    size = next(a.shape[d] for a, d in zip(args, dims) if d is not None)
+    args = [batching.bdim_at_front(a, d, size) for a, d in zip(args, dims)]
+    out = _hub_sort_p.bind(*args, dimension=dimension + 1,
+                           is_stable=is_stable, num_keys=num_keys)
+    return out, [0] * len(out)
+
+
+batching.primitive_batchers[_hub_sort_p] = _hub_sort_batch
+
+
+def hub_mex(vertex: jnp.ndarray, color: jnp.ndarray,
+            num_rows: int) -> jnp.ndarray:
+    """Per-row minimum excluded positive color of ``(row, color)`` entries,
+    for the hub rows of a binned ELL layout: rows too long for the slab.
+
+    vertex: [M] int32 rows; a row ``>= num_rows`` is an inert entry.
+    color:  [M] int32 >= 0 forbidden colors.
+    Returns [num_rows] int32 >= 1; a row without entries reads 1, so the
+    mex of a row split between a slab and this path is the larger of the
+    two (every row takes one path whole, the other seeing it empty).
+
+    Its work grows with the entries, not with the rows' degree: one sort
+    (named ``hub_mex``), then each row's answer is read at the first gap
+    after its first entry, found by a binary search, with no scatter. The
+    same function as :func:`segment_mex` with a synthetic ``(v, 0)`` entry
+    per row."""
+    if vertex.shape[0] == 0:
+        return jnp.ones((num_rows,), jnp.int32)
+    v, c = _hub_sort_p.bind(vertex.astype(jnp.int32),
+                            color.astype(jnp.int32), dimension=0,
+                            is_stable=False, num_keys=2)
+    n = v.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    next_v = jnp.concatenate([v[1:], jnp.full((1,), -1, jnp.int32)])
+    next_c = jnp.concatenate([c[1:], jnp.zeros((1,), jnp.int32)])
+    gap = (next_v != v) | (next_c > c + 1)
+    # the first gap at or after each entry; a row's last entry is one
+    next_gap = lax.cummin(jnp.where(gap, idx, n - 1), reverse=True)
+    start = jnp.searchsorted(
+        v, jnp.arange(num_rows + 1, dtype=jnp.int32)).astype(jnp.int32)
+    first = jnp.minimum(start[:-1], n - 1)
+    # colors from the row's first entry up to its first gap run without a
+    # hole; a row whose smallest color is 2 or more is free at 1
+    found = (start[:-1] < start[1:]) & (c[first] <= 1)
+    return jnp.where(found, c[next_gap[first]] + 1, 1).astype(jnp.int32)

@@ -25,7 +25,8 @@ ELL slab it
     the same slab read;
   * emits the mex (the row's next color) and the per-row conflict flag on
     the last slot tile — one read of the ELL slab per round instead of
-    three (`benchmarks/roofline.py --round` measures exactly this).
+    three (the benchmark's ``round_fused_roofline`` measures it on the
+    chip).
 
 The gather stays OUTSIDE the kernel (DESIGN.md §2 / §FusedRound:
 "regularize, then go fast"): neighbor colors arrive as a pre-gathered,
@@ -41,9 +42,14 @@ with two predicate bits:
 Entries without either bit (slab padding, masked-out edges) are inert:
 color 0 is always forbidden by construction, exactly as in ``firstfit``.
 
-Colors are assumed ``< 32*words`` (the greedy Δ+2 bound; out-of-range
-colors drop from the bitset just like the bitmap backend's out-of-range
-scatters — they can never lower a mex that provably stays in range).
+The bound is per bin: a row of at most ``D`` entries has its mex at most
+``D + 1``, so ``32*words >= D + 2`` for the slab's width ``D`` holds every
+mex a row can take. Neighbour colors at or above ``32*words`` drop from the
+bitset just like the bitmap backend's out-of-range scatters — they can
+never lower a mex that provably stays in range. Under a binned layout
+(``repro.core.engine.HUB_WIDTH``) the slab holds only the rows of degree
+at most its width, and the words come from that width, not from the
+graph's max degree; longer rows take the per-edge hub path.
 """
 from __future__ import annotations
 

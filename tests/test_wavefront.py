@@ -195,7 +195,8 @@ def test_report_and_plan_call_carry_the_spill_count():
     rep = plan(g)
     assert rep.wavefront_spills == 0
     assert rep.sweeps_per_round[0] == 1
-    assert spans.recent_calls(1)[0].counters == {"wavefront_spills": 0}
+    assert spans.recent_calls(1)[0].counters == {
+        "wavefront_spills": 0, "hub_rows": 0, "hub_edges": 0}
     one = color(g, spec)
     np.testing.assert_array_equal(one.colors, rep.colors)
     assert one.wavefront_spills == 0
