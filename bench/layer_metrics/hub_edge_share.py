@@ -1,9 +1,13 @@
 """Share of a coloring's forbidding-edge entries that the fused engine's
 per-edge hub path takes, in %: the mean over the window's ``plan.call``
-records (``repro.core.spans``) of the ``hub_edges`` counter, which is
-``ColoringReport.hub_edges`` (the directed edge entries of the rows too long
-for a binned ELL slab), over the coloring's directed edge entries. ``None``
-where the program keeps no such counter."""
+records (``repro.core.spans``) of the ``hub_edges`` counter over the
+coloring's directed edge entries. ``hub_edges`` is
+``ColoringReport.hub_edges``: the directed entries of the rows too long for
+a binned ELL slab. Each directed entry (u, v) is the entry through which
+v's color forbids u's, so the denominator counts every forbidding-edge
+entry a full sweep reads; later rounds read fewer, in the same rows. The
+counter is fixed by the graph and the bin, counted on the host as the
+layout is built. ``None`` where the program keeps no such counter."""
 from bench.plan_spans import window_calls
 
 

@@ -1,8 +1,10 @@
 """Device time of the fused engine's per-edge hub path per coloring, in
-ms: the summed self time of the window's ops named ``hub_mex`` (the hub
-path's sort, ``repro.core.mex.hub_mex``, its bulk; the comparisons and
-gathers around it fuse into ops without its name) over the window's
-colorings. ``None`` where no such op ran."""
+ms: the summed self time of the window's ops named ``hub_mex`` over the
+window's colorings. Those are the hub path's sort alone
+(``repro.core.mex.hub_sort``): its window slices, segment-min, comparisons
+and gathers fuse into unnamed ``fusion.N`` (or ``scatter.N``) ops, which
+this reader does not count, so it reads below the whole path's time.
+``None`` where no such op ran."""
 from bench.trace_reduce import kernel_time
 
 PREFIX = "hub_mex"

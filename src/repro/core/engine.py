@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mex import hub_mex, segment_mex
+from .mex import segment_mex
 
 # A bound mex engine: (key_v [M], key_c [M]) -> mex [V] int32 (>= 1).
 # key_v[i] is the vertex the edge forbids (num_vertices = inert padding);
@@ -67,8 +67,9 @@ _INT32_MAX = jnp.iinfo(jnp.int32).max
 # above it (a binned layout, :meth:`MexBackend.layout_width`). Rows of
 # degree at most this keep the slab and the ``round_fused`` kernel, whose
 # bitset is sized from it; longer rows (hubs) take the per-edge
-# :func:`repro.core.mex.hub_mex`, whose work grows with their edges. The
-# slab's memory grows with it, the hub edges' share falls with it.
+# :func:`repro.core.mex.segment_mex` (``hub=True``), whose work grows with
+# their edges. The slab's memory grows with it, the hub edges' share falls
+# with it.
 HUB_WIDTH = 256
 
 
@@ -342,8 +343,9 @@ class FusedPallasMexBackend(MexBackend):
     binned (:meth:`layout_width`): the slab is ``HUB_WIDTH`` wide, and
     every edge of a longer row carries the marker slot ``HUB_WIDTH``, which
     the slab scatter drops. Those hub rows take the per-edge
-    :func:`repro.core.mex.hub_mex` instead, and a row's mex is the larger
-    of the two paths' (the path a row does not take reads 1). So the
+    :func:`repro.core.mex.segment_mex` (``hub=True``) instead, and a row's
+    mex is the larger of the two paths' (the path a row does not take reads
+    1). So the
     slab, the kernel's bitset and the host slot map are sized from the
     bin, not from the max degree.
 
@@ -398,8 +400,8 @@ class FusedPallasMexBackend(MexBackend):
             m, _ = round_fused(ent[:V], jnp.zeros((V,), jnp.int32),
                                words=words, interpret=interp)
             if hub:
-                m = jnp.maximum(m, hub_mex(
-                    jnp.where(ell_slot == D, key_v, V), key_c, V))
+                m = jnp.maximum(m, segment_mex(
+                    jnp.where(ell_slot == D, key_v, V), key_c, V, hub=True))
             return m
 
         return mex
@@ -427,8 +429,8 @@ class FusedPallasMexBackend(MexBackend):
             m, _ = round_fused(ent[:cap], jnp.zeros((cap,), jnp.int32),
                                words=words, interpret=interp)
             if hub:
-                m = jnp.maximum(m, hub_mex(
-                    jnp.where(slot == D, key_v, cap), key_c, cap))
+                m = jnp.maximum(m, segment_mex(
+                    jnp.where(slot == D, key_v, cap), key_c, cap, hub=True))
             return m
 
         return mex

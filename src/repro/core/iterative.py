@@ -69,7 +69,7 @@ from .engine import (EngineSpec, MexFn, SlabMexFn, SweepSpec,
 from .frontier import (compact_frontier, frontier_conflicts, frontier_counts,
                        frontier_sweep)
 from .graph import DeviceGraph, pad_bucket
-from .mex import hub_mex
+from .mex import segment_mex
 
 # A full round's phase 1 runs as a lockstep wavefront while the envelope
 # holds at least this many directed edges per static offset class
@@ -184,9 +184,9 @@ def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
     the slot ``hub_width``) the hub rows' forbidding edges sort after
     every class, in class order, and step k also takes its class's hub
     edges in a window of their own, sized from :func:`hub_windows` by
-    their count, for :func:`repro.core.mex.hub_mex`; a thread's colour is
-    the larger of the two mexes. A class spills when either part is over
-    ``cap_e``.
+    their count, for :func:`repro.core.mex.segment_mex` (``hub=True``); a
+    thread's colour is the larger of the two mexes. A class spills when
+    either part is over ``cap_e``.
 
     ``mex`` is the engine bound to the whole graph, ``mex_slab`` bound to
     ``cap_v`` rows (of the slab's width alone where the layout is
@@ -254,19 +254,28 @@ def lockstep_wavefront(mex: MexFn, mex_slab: SlabMexFn, *,
             live = (w >= lo - s) & (w < lo - s + n)
             row = (lax.dynamic_slice(skey, (s,), (size,))
                    - (num_classes + k) * cap_v)
-            return hub_mex(jnp.where(live, row, cap_v),
-                           cpad[lax.dynamic_slice(sdst, (s,), (size,))],
-                           cap_v)
+            return segment_mex(jnp.where(live, row, cap_v),
+                               cpad[lax.dynamic_slice(sdst, (s,), (size,))],
+                               cap_v, hub=True)
         return run
 
     sizes = hub_windows(cap_e)
-    branches = [hub_window(z) for z in sizes]
+    windows = [hub_window(z) for z in sizes]
 
     def hub_step(k, cpad):
         # the smallest window that holds the class's hub edges
         n = starts[num_classes + k + 1] - starts[num_classes + k]
         which = (n > jnp.asarray(sizes[:-1], jnp.int32)).sum()
-        return lax.switch(which, branches, k, cpad)
+        m = jnp.ones((cap_v,), jnp.int32)
+        for i, run in enumerate(windows):
+            # a loop of at most one trip per size, not a switch: under vmap
+            # (plan.map) a switch runs every size on every step, a while
+            # loop only the sizes some graph of the batch needs
+            m = lax.while_loop(
+                lambda c, i=i: c[0] == i,
+                lambda c, run=run: (jnp.int32(-1), run(k, cpad)),
+                (which, m))[1]
+        return m
 
     cpad0 = jnp.concatenate([colors, jnp.zeros((1,), jnp.int32)])
     cpad = lax.fori_loop(0, bs, step, cpad0)
@@ -309,7 +318,7 @@ def _iterative_impl(g: DeviceGraph, colors0=None, pending0=None, *,
                        ell_slot=g.ell_slot, ell_width=g.ell_width,
                        max_degree=g.max_degree)
     # a binned ELL layout's slab width: rows longer than it (hubs) take
-    # the per-edge path (repro.core.mex.hub_mex), 0 where none is binned
+    # the per-edge path (repro.core.mex.segment_mex), 0 where none is binned
     hub = g.hub_width if backend.needs_ell else 0
     # phase 1 of a full round in lockstep offset order where the static
     # class count allows (lockstep_wavefront), else chaotic sweeps

@@ -6,9 +6,9 @@ MexBackend` (the registry's layout-free default); the other registered
 backends (``"bitmap"``, ``"ell_pallas"``) compute the same function through
 different formulations — see ``repro.core.engine`` for the registry and
 DESIGN.md §Engine for the parity contract. Drivers go through
-``MexBackend.bind(...)``'s returned mex closure; the one exception is
-:func:`hub_mex`, the per-edge path of a binned ELL layout's hub rows, which
-the lockstep wavefront calls on its own hub windows.
+``MexBackend.bind(...)``'s returned mex closure; the one exception is the
+per-edge path of a binned ELL layout's hub rows (``segment_mex(...,
+hub=True)``), which the lockstep wavefront calls on its own hub windows.
 
 Given a multiset of (vertex, forbidden-color) pairs, compute per vertex the
 minimum *positive* integer not present. The trick: lexicographically sort the
@@ -16,24 +16,30 @@ pairs (two-key ``lax.sort`` — no int64 composite keys, TPU-friendly) and emit
 a candidate ``c+1`` wherever a "gap" occurs (next entry belongs to another
 vertex, or skips past ``c+1``); the segment-min of candidates is the mex.
 
-Callers must guarantee every live vertex contributes at least one entry; the
-canonical way is to append a synthetic ``(v, 0)`` pair per vertex (color 0 ==
-"uncolored" never collides with real colors >= 1 and seeds the candidate
-``1``) — ``SortMexBackend.bind`` does exactly this.
+Callers must guarantee every live vertex contributes at least one entry
+(except under ``hub=True``); the canonical way is to append a synthetic
+``(v, 0)`` pair per vertex (color 0 == "uncolored" never collides with real
+colors >= 1 and seeds the candidate ``1``) — ``SortMexBackend.bind`` does
+exactly this.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax._src.lax.lax import _sort_lower
 from jax.extend.core import Primitive
 from jax.interpreters import batching, mlir
+
+try:  # private: a JAX without it sorts the hub path under the plain name
+    from jax._src.lax.lax import _sort_lower
+except ImportError:  # pragma: no cover - depends on the installed JAX
+    _sort_lower = None
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
-def segment_mex(vertex: jnp.ndarray, color: jnp.ndarray, num_vertices: int) -> jnp.ndarray:
+def segment_mex(vertex: jnp.ndarray, color: jnp.ndarray, num_vertices: int,
+                *, hub: bool = False) -> jnp.ndarray:
     """Per-vertex minimum excluded positive color.
 
     vertex: [M] int32 ids in [0, num_vertices]; id == num_vertices is inert
@@ -41,14 +47,28 @@ def segment_mex(vertex: jnp.ndarray, color: jnp.ndarray, num_vertices: int) -> j
     color:  [M] int32 >= 0 forbidden colors.
     Returns [num_vertices] int32 mex (>= 1) — garbage for vertices with no
     entries (callers append synthetic (v, 0) entries to avoid that).
+
+    hub: the per-edge path of the binned ``fused_pallas`` engine's hub
+    rows, which appends no synthetic entries: a vertex with no entries
+    reads 1 (so a row's mex is the larger of the slab's and this path's,
+    the path that does not take the row reading 1), and the sort is
+    :func:`hub_sort`, named ``hub_mex`` in a device trace.
     """
-    v_s, c_s = lax.sort((vertex.astype(jnp.int32), color.astype(jnp.int32)), num_keys=2)
+    ops = (vertex.astype(jnp.int32), color.astype(jnp.int32))
+    v_s, c_s = hub_sort(ops) if hub else lax.sort(ops, num_keys=2)
     next_v = jnp.concatenate([v_s[1:], jnp.full((1,), num_vertices + 1, jnp.int32)])
     next_c = jnp.concatenate([c_s[1:], jnp.zeros((1,), jnp.int32)])
     seg_end = next_v != v_s
     gap = seg_end | (next_c > c_s + 1)
     cand = jnp.where(gap, c_s + 1, _INT32_MAX)
+    if hub:
+        # what a synthetic (v, 0) entry would add: a vertex whose smallest
+        # forbidden color is 2 or more is free at 1
+        prev_v = jnp.concatenate([jnp.full((1,), -1, jnp.int32), v_s[:-1]])
+        cand = jnp.where((prev_v != v_s) & (c_s > 1), 1, cand)
     mex = jax.ops.segment_min(cand, v_s, num_segments=num_vertices + 1)
+    if hub:  # an empty segment reads the identity of min
+        mex = jnp.where(mex == _INT32_MAX, 1, mex)
     return mex[:num_vertices]
 
 
@@ -56,13 +76,16 @@ def segment_mex(vertex: jnp.ndarray, color: jnp.ndarray, num_vertices: int) -> j
 # after the primitive that emitted it, so this sort shows in a device trace
 # as ``hub_mex.N`` where a ``lax.sort`` shows as ``sort.N`` like every other
 # sort of the program; the benchmark's ``hub_mex_ms`` reads it by that name.
-# It lowers and batches exactly as ``lax.sort``.
+# It lowers and batches exactly as ``lax.sort``. Its lowering is JAX's own
+# private ``_sort_lower`` (checked by tests/test_tpu_compile.py); where the
+# installed JAX lacks it, ``hub_sort`` is ``lax.sort`` and the name is lost.
 _hub_sort_p = Primitive("hub_mex")
 _hub_sort_p.multiple_results = True
 _hub_sort_p.def_abstract_eval(lambda *avals, **params: list(avals))
 _hub_sort_p.def_impl(lambda *ops, dimension, is_stable, num_keys: lax.sort(
     ops, dimension=dimension, is_stable=is_stable, num_keys=num_keys))
-mlir.register_lowering(_hub_sort_p, _sort_lower)
+if _sort_lower is not None:
+    mlir.register_lowering(_hub_sort_p, _sort_lower)
 
 
 def _hub_sort_batch(args, dims, *, dimension, is_stable, num_keys):
@@ -76,38 +99,10 @@ def _hub_sort_batch(args, dims, *, dimension, is_stable, num_keys):
 batching.primitive_batchers[_hub_sort_p] = _hub_sort_batch
 
 
-def hub_mex(vertex: jnp.ndarray, color: jnp.ndarray,
-            num_rows: int) -> jnp.ndarray:
-    """Per-row minimum excluded positive color of ``(row, color)`` entries,
-    for the hub rows of a binned ELL layout: rows too long for the slab.
-
-    vertex: [M] int32 rows; a row ``>= num_rows`` is an inert entry.
-    color:  [M] int32 >= 0 forbidden colors.
-    Returns [num_rows] int32 >= 1; a row without entries reads 1, so the
-    mex of a row split between a slab and this path is the larger of the
-    two (every row takes one path whole, the other seeing it empty).
-
-    Its work grows with the entries, not with the rows' degree: one sort
-    (named ``hub_mex``), then each row's answer is read at the first gap
-    after its first entry, found by a binary search, with no scatter. The
-    same function as :func:`segment_mex` with a synthetic ``(v, 0)`` entry
-    per row."""
-    if vertex.shape[0] == 0:
-        return jnp.ones((num_rows,), jnp.int32)
-    v, c = _hub_sort_p.bind(vertex.astype(jnp.int32),
-                            color.astype(jnp.int32), dimension=0,
-                            is_stable=False, num_keys=2)
-    n = v.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    next_v = jnp.concatenate([v[1:], jnp.full((1,), -1, jnp.int32)])
-    next_c = jnp.concatenate([c[1:], jnp.zeros((1,), jnp.int32)])
-    gap = (next_v != v) | (next_c > c + 1)
-    # the first gap at or after each entry; a row's last entry is one
-    next_gap = lax.cummin(jnp.where(gap, idx, n - 1), reverse=True)
-    start = jnp.searchsorted(
-        v, jnp.arange(num_rows + 1, dtype=jnp.int32)).astype(jnp.int32)
-    first = jnp.minimum(start[:-1], n - 1)
-    # colors from the row's first entry up to its first gap run without a
-    # hole; a row whose smallest color is 2 or more is free at 1
-    found = (start[:-1] < start[1:]) & (c[first] <= 1)
-    return jnp.where(found, c[next_gap[first]] + 1, 1).astype(jnp.int32)
+def hub_sort(operands):
+    """``lax.sort(operands, num_keys=len(operands))`` under the name
+    ``hub_mex``, where the installed JAX allows it."""
+    if _sort_lower is None:
+        return lax.sort(operands, num_keys=len(operands))
+    return _hub_sort_p.bind(*operands, dimension=0, is_stable=False,
+                            num_keys=len(operands))

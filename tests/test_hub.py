@@ -1,7 +1,8 @@
 """The binned ELL layout (repro.core.engine.HUB_WIDTH): a ``fused_pallas``
 plan whose envelope max degree is above the bin keeps the slab and the
 ``round_fused`` kernel for the rows that fit it and gives longer rows (hubs)
-the per-edge ``hub_mex`` path. Colors stay bit-identical to the sort engine
+the per-edge path (``segment_mex(..., hub=True)``, its sort named
+``hub_mex``). Colors stay bit-identical to the sort engine
 and to the serial greedy oracle on RMAT-B, the paper's skewed family, across
 the wavefront, the frontier rounds, forced spills, ``plan.map``, a seeded
 ``recolor`` and DATAFLOW; the counters equal a numpy count; a plan whose
@@ -20,7 +21,7 @@ from repro.core.engine import get_backend
 from repro.core.frontier import resolve_frontier
 from repro.core.graph import pad_bucket
 from repro.core.iterative import _iterative_impl, hub_windows
-from repro.core.mex import hub_mex, segment_mex
+from repro.core.mex import segment_mex
 
 # (bin width, scale): the shipped bin on the largest scale the CPU tests
 # run, where a few RMAT-B rows pass it, and a narrow bin at scale 10,
@@ -179,17 +180,24 @@ def test_host_layout_marks_whole_hub_rows():
     assert full.hub_width == 0 and full.ell_width == g.max_degree()
 
 
-def test_hub_mex_matches_segment_mex():
+@pytest.mark.parametrize("synthetic", [False, True],
+                         ids=["bare", "synthetic"])
+def test_hub_mex_matches_segment_mex(synthetic):
+    """The hub path's mex (``hub=True``: a row without entries reads 1,
+    no synthetic entries needed, and harmless where given) is the sort
+    engine's mex with a synthetic (v, 0) entry per row."""
     rng = np.random.default_rng(7)
-    mex = jax.jit(hub_mex, static_argnums=2)
+    mex = jax.jit(functools.partial(segment_mex, hub=True),
+                  static_argnums=2)
     for _ in range(50):
         rows, m = int(rng.integers(1, 24)), int(rng.integers(1, 80))
         v = rng.integers(0, rows + 3, m).astype(np.int32)
         c = rng.integers(0, 9, m).astype(np.int32)
-        want = segment_mex(
-            jnp.asarray(np.concatenate([v, np.arange(rows)])),
-            jnp.asarray(np.concatenate([c, np.zeros(rows, np.int32)])),
-            rows)
+        full_v = np.concatenate([v, np.arange(rows)]).astype(np.int32)
+        full_c = np.concatenate([c, np.zeros(rows, np.int32)])
+        want = segment_mex(jnp.asarray(full_v), jnp.asarray(full_c), rows)
+        if synthetic:
+            v, c = full_v, full_c
         np.testing.assert_array_equal(
             np.asarray(mex(jnp.asarray(v), jnp.asarray(c), rows)),
             np.asarray(want))

@@ -91,7 +91,7 @@ def test_binned_slab_and_hub_path_compile_for_v5e(one_chip):
     over a ``2^14``-row slab of the bin's width, its bitset sized from the
     bin, and the hub path's named sort over a full ``cap_e`` window."""
     from repro.core.engine import HUB_WIDTH, num_color_words
-    from repro.core.mex import hub_mex
+    from repro.core.mex import segment_mex
     rows, window = 1 << 14, 327680
     words = num_color_words(HUB_WIDTH + 1)
     kernel = jax.jit(lambda x, own: round_fused(x, own, words=words,
@@ -99,7 +99,7 @@ def test_binned_slab_and_hub_path_compile_for_v5e(one_chip):
     compiled = kernel.lower(_sds((rows, HUB_WIDTH), jnp.int32, one_chip),
                             _sds((rows,), jnp.int32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    hub = jax.jit(lambda v, c: hub_mex(v, c, rows))
+    hub = jax.jit(lambda v, c: segment_mex(v, c, rows, hub=True))
     e = _sds((window,), jnp.int32, one_chip)
     text = hub.lower(e, e).compile().as_text()
     assert "hub_mex" in text and " sort(" in text
